@@ -116,6 +116,7 @@ from repro.core.costmodel import (fwd_flops_per_token, kv_cache_bytes,
                                   prefill_chunk_bytes)
 from repro.configs.base import ShapeConfig
 from repro.core.topology import ChipletTopology
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.engine import EngineConfig, ServeEngine
 
 
@@ -952,6 +953,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CI run: few requests, fast")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         args.requests = 8
         args.mean_gap = 1.0

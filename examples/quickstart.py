@@ -11,6 +11,7 @@ from jax.sharding import Mesh
 
 from repro.configs import REGISTRY, reduced_config
 from repro.core.topology import ChipletTopology
+from repro.launch.compile_cache import enable_compile_cache
 from repro.data.pipeline import (ShardedLoader, SyntheticCorpus,
                                  write_corpus_shards)
 from repro.launch.steps import make_generate, make_prefill
@@ -19,6 +20,7 @@ from repro.runtime.trainer import Trainer, TrainerConfig
 
 
 def main():
+    enable_compile_cache()
     cfg = reduced_config(REGISTRY["llama3-8b"])
     print(f"model: {cfg.name} ({cfg.n_layers}L d={cfg.d_model})")
 

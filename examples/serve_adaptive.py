@@ -16,10 +16,12 @@ import numpy as np
 
 from repro.configs import REGISTRY, reduced_config
 from repro.core.topology import ChipletTopology
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.engine import EngineConfig, ServeEngine
 
 
 def main():
+    enable_compile_cache()
     cfg = reduced_config(REGISTRY["mixtral-8x22b"])
     topo = ChipletTopology(n_pods=1, groups_per_pod=4, chips_per_group=2)
     eng = ServeEngine(cfg, topo, EngineConfig(max_batch=2, max_len=96,

@@ -25,10 +25,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 LANES = 128
+# sentinel "position" for KV entries that must never win a mask test:
+# never-written ring slots already carry small negatives, this marks
+# masked chunk keys and block padding (far enough below zero that
+# ``kp > qp - W`` can never resurrect it)
+NEVER = -(2 ** 30)
 
 
 def _block_visible(iq, jk, bq, bkv, causal, window):
@@ -100,7 +105,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def flash_attention_fwd(q, k, v, *, causal=True, window=0,
                         block_q=256, block_kv=256, hq_per_kv=1,
-                        interpret=False):
+                        interpret=None):
     """q: (BHq, Sq, dh); k/v: (BHkv, Skv, dh) with BHq = BHkv * hq_per_kv.
 
     Returns (out (BHq, Sq, dh), lse (BHq, Sq, LANES) — lse broadcast on lanes).
@@ -139,9 +144,9 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=0,
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
     return out, lse
 
@@ -161,11 +166,32 @@ def _ring_block_visible(iq, jk, bq, bkv, ring):
     return (kv_lo < ring) | (kv_lo - ring <= q_hi)
 
 
-def _ring_fwd_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref,
+def _ring_kv_positions(pos, n_tokens, jk, *, ring, bq, bkv):
+    """Absolute position held by each KV column of block ``jk`` for one
+    stream whose chunk starts at ``pos`` with ``n_tokens`` live tokens.
+
+    Columns [0, ring) are ring slots BEFORE the chunk is written: slot j
+    holds the latest position p <= pos-1 with p = j (mod ring), negative
+    when never written.  Columns [ring, ring+C) are chunk keys pos+t',
+    live while t' < n_tokens.  Everything else (masked chunk keys, block
+    padding) carries NEVER."""
+    last = pos - 1
+    r = jax.lax.rem(last, ring)                        # scalar floor mod
+    r = jnp.where(r < 0, r + ring, r)
+    j = jk * bkv + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
+    ring_pos = last - r + j - jnp.where(j <= r, 0, ring)
+    t = j - ring
+    chunk_pos = jnp.where(t < n_tokens, pos + t, NEVER)
+    return jnp.where(j < ring, ring_pos, chunk_pos)
+
+
+def _ring_fwd_kernel(pos_ref, nt_ref, q_ref, k_ref, v_ref, o_ref,
                      m_scr, l_scr, acc_scr, *, scale, ring, window, softcap,
-                     bq, bkv, n_kv):
+                     bq, bkv, n_kv, heads):
+    b = pl.program_id(0) // heads
     iq = pl.program_id(1)
     jk = pl.program_id(2)
+    pos = pos_ref[b]
 
     @pl.when(jk == 0)
     def _init():
@@ -183,15 +209,14 @@ def _ring_fwd_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref,
             preferred_element_type=jnp.float32) * scale  # (bq, bkv)
         if softcap:
             s = softcap * jnp.tanh(s / softcap)
-        # absolute positions carried in by the wrapper: q rows are pos+t,
-        # KV entries are the slot's held position (ring segment, negative =
-        # never written), pos+t' for live chunk keys, or a sentinel far
-        # below zero for idle/short-chunk keys and block padding.  One band
-        # test then expresses all three dense masks: causality (kp <= qp),
-        # ring eviction incl. intra-chunk self-eviction for C > W
-        # (kp > qp - ring), and never-written slots (kp >= 0).
-        qp = qpos_ref[0][:, None].astype(jnp.int32)    # (bq, 1)
-        kp = kpos_ref[0][None, :].astype(jnp.int32)    # (1, bkv)
+        # absolute positions, rebuilt here from the stream's two prefetched
+        # scalars: q rows are pos+t, KV columns as in _ring_kv_positions.
+        # One band test then expresses all three dense masks: causality
+        # (kp <= qp), ring eviction incl. intra-chunk self-eviction for
+        # C > W (kp > qp - ring), and never-written slots (kp >= 0).
+        qp = pos + iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
+        kp = _ring_kv_positions(pos, nt_ref[b], jk, ring=ring, bq=bq,
+                                bkv=bkv)
         mask = (kp >= 0) & (kp <= qp) & (kp > qp - ring)
         if window:
             mask &= kp > qp - window
@@ -217,24 +242,26 @@ def _ring_fwd_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref,
         o_ref[0] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
 
 
-def ring_chunk_attention_fwd(q, k, v, q_pos, kv_pos, *, ring, window=0,
+def ring_chunk_attention_fwd(q, k, v, pos, n_tokens, *, ring, window=0,
                              softcap=0.0, block_q=32, block_kv=32,
-                             hq_per_kv=1, interpret=False):
+                             hq_per_kv=1, interpret=None):
     """Forward-only blocked attention over [prior ring, chunk keys].
 
     q: (BHq, Cp, dh) chunk queries, head-major, padded to a block_q
     multiple; k/v: (BHkv, Lp, dh) the concatenated [ring, chunk] KV, padded
-    to a block_kv multiple; q_pos: (B, Cp) int32 absolute query positions;
-    kv_pos: (B, Lp) int32 absolute KV positions with negative sentinels for
-    never-written slots, masked chunk keys, and padding.  ``ring`` is the
-    ring width W (the implicit eviction window).  Returns (BHq, Cp, dh).
+    to a block_kv multiple; pos: (B,) int32 absolute position of chunk
+    token 0; n_tokens: (B,) int32 live chunk tokens.  ``ring`` is the ring
+    width W (the implicit eviction window).  Returns (BHq, Cp, dh).
 
-    The live transient per grid step is one (block_q, block_kv) f32 score
-    block plus the online-softmax state — never the dense (C, W+C) block.
+    ``pos``/``n_tokens`` ride in SMEM as scalar-prefetch operands and every
+    query/KV position is derived in-kernel, so no (B, L) position array
+    needs a lane-aligned VMEM block.  The live transient per grid step is
+    one (block_q, block_kv) f32 score block plus the online-softmax state
+    — never the dense (C, W+C) block.
     """
     BH, Cp, dh = q.shape
     Lp = k.shape[1]
-    B = q_pos.shape[0]
+    B = pos.shape[0]
     heads = BH // B
     bq = min(block_q, Cp)
     bkv = min(block_kv, Lp)
@@ -245,29 +272,32 @@ def ring_chunk_attention_fwd(q, k, v, q_pos, kv_pos, *, ring, window=0,
 
     kernel = functools.partial(
         _ring_fwd_kernel, scale=scale, ring=ring, window=window,
-        softcap=softcap, bq=bq, bkv=bkv, n_kv=n_kv)
+        softcap=softcap, bq=bq, bkv=bkv, n_kv=n_kv, heads=heads)
 
     out = pl.pallas_call(
         kernel,
-        grid=(BH, n_q, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, bq, dh), lambda b, iq, jk: (b, iq, 0)),
-            pl.BlockSpec((1, bkv, dh), lambda b, iq, jk: (b // G, jk, 0)),
-            pl.BlockSpec((1, bkv, dh), lambda b, iq, jk: (b // G, jk, 0)),
-            pl.BlockSpec((1, bq), lambda b, iq, jk: (b // heads, iq)),
-            pl.BlockSpec((1, bkv), lambda b, iq, jk: (b // heads, jk)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, dh), lambda b, iq, jk: (b, iq, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(BH, n_q, n_kv),
+            in_specs=[
+                pl.BlockSpec((1, bq, dh), lambda b, iq, jk, *_: (b, iq, 0)),
+                pl.BlockSpec((1, bkv, dh),
+                             lambda b, iq, jk, *_: (b // G, jk, 0)),
+                pl.BlockSpec((1, bkv, dh),
+                             lambda b, iq, jk, *_: (b // G, jk, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, bq, dh),
+                                   lambda b, iq, jk, *_: (b, iq, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((bq, LANES), jnp.float32),
+                pltpu.VMEM((bq, LANES), jnp.float32),
+                pltpu.VMEM((bq, dh), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((BH, Cp, dh), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, dh), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(q, k, v, q_pos, kv_pos)
+        interpret=resolve_interpret(interpret),
+    )(pos.astype(jnp.int32), n_tokens.astype(jnp.int32), q, k, v)
     return out
 
 
@@ -366,7 +396,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=0,
                         block_q=256, block_kv=256, hq_per_kv=1,
-                        interpret=False):
+                        interpret=None):
     """Returns (dq, dk, dv) with GQA reduction over the q-head group."""
     BH, Sq, dh = q.shape
     BHkv, Skv, _ = k.shape
@@ -393,9 +423,9 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=0,
         out_specs=pl.BlockSpec((1, bq, dh), lambda b, iq, jk: (b, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, dh), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, dh), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -423,9 +453,9 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=0,
             pltpu.VMEM((bkv, dh), jnp.float32),
             pltpu.VMEM((bkv, dh), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

@@ -3,9 +3,10 @@ forward-only ring-chunk attention used by the serving fused-prefill path.
 
 ``flash_attention(q, k, v)`` takes model-layout tensors (B, S, H, dh) and
 handles head-major reshaping, GQA head mapping, and the Pallas fwd/bwd
-kernels.  ``interpret=None`` (the default) resolves per backend: TPU
-compiles the real kernel, everything else runs the kernel bodies in
-interpret mode for validation.  Pass an explicit bool to override.
+kernels.  ``interpret=None`` (the default) resolves per backend through
+``repro.kernels.resolve_interpret``: TPU compiles the real kernel,
+everything else runs the kernel bodies in interpret mode for validation.
+Pass an explicit bool to override.
 """
 from __future__ import annotations
 
@@ -16,21 +17,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention import kernel as K
-
-# sentinel "position" for KV entries that must never win a mask test:
-# never-written ring slots already carry small negatives, this marks
-# masked chunk keys and block padding (far enough below zero that
-# ``kp > qp - W`` can never resurrect it)
-_NEVER = -(2 ** 30)
-
-
-def _default_interpret() -> bool:
-    """Interpret Pallas kernel bodies everywhere except real TPU."""
-    return jax.default_backend() != "tpu"
-
-
-def _resolve_interpret(interpret):
-    return _default_interpret() if interpret is None else interpret
 
 
 def _to_head_major(x):
@@ -60,8 +46,7 @@ def _fwd(q, k, v, causal, window, block_q, block_kv, interpret):
     vf = _to_head_major(v)
     out, lse = K.flash_attention_fwd(
         qf, kf, vf, causal=causal, window=window, block_q=block_q,
-        block_kv=block_kv, hq_per_kv=G,
-        interpret=_resolve_interpret(interpret))
+        block_kv=block_kv, hq_per_kv=G, interpret=interpret)
     return _from_head_major(out, B, Hq), (qf, kf, vf, out, lse, B, Hq, Hkv)
 
 
@@ -76,8 +61,7 @@ def _bwd_rule(causal, window, block_q, block_kv, interpret, res, g):
     gf = _to_head_major(g)
     dq, dk, dv = K.flash_attention_bwd(
         qf, kf, vf, outf, lse, gf, causal=causal, window=window,
-        block_q=block_q, block_kv=block_kv, hq_per_kv=G,
-        interpret=_resolve_interpret(interpret))
+        block_q=block_q, block_kv=block_kv, hq_per_kv=G, interpret=interpret)
     return (_from_head_major(dq, B, Hq),
             _from_head_major(dk, B, Hkv),
             _from_head_major(dv, B, Hkv))
@@ -105,9 +89,10 @@ def ring_chunk_attention(q, k_new, v_new, k_cache, v_cache, pos, n_tokens, *,
     position of chunk token 0, n_tokens: (B,) in [0, C] — but the score
     transient is one (block_q, block_kv) tile per grid step instead of the
     dense (C, W+C) block.  All three dense masks collapse into one band
-    test on absolute positions computed here: ring keys carry the slot's
-    held position (``cache_positions`` on the pre-chunk ring), chunk key
-    t' carries pos+t' while t' < n_tokens and a -2^30 sentinel otherwise,
+    test on absolute positions the kernel derives from ``pos`` and
+    ``n_tokens``: ring keys carry the slot's held position
+    (``cache_positions`` on the pre-chunk ring), chunk key t' carries
+    pos+t' while t' < n_tokens and a -2^30 sentinel otherwise,
     and ``kp > qp - W`` expresses both ring eviction and intra-chunk
     self-eviction, so chunks wider than the ring (C > W) score exactly.
     Rows with no visible key (idle streams at pos 0, q-block padding)
@@ -122,29 +107,12 @@ def ring_chunk_attention(q, k_new, v_new, k_cache, v_cache, pos, n_tokens, *,
     Cp = -(-C // bq) * bq
     Lp = -(-L // bkv) * bkv
 
-    pos = pos.astype(jnp.int32)
-    n_tokens = n_tokens.astype(jnp.int32)
-    q_pos = pos[:, None] + jnp.arange(Cp, dtype=jnp.int32)[None, :]
-    # prior ring: positions held BEFORE the chunk (pos-1 = last written);
-    # never-written slots come out negative, same as cache_positions
-    slots = jnp.arange(W, dtype=jnp.int32)
-    last = pos[:, None] - 1
-    ring_pos = last - ((last - slots[None, :]) % W)
-    tc = jnp.arange(C, dtype=jnp.int32)
-    chunk_pos = jnp.where(tc[None, :] < n_tokens[:, None],
-                          pos[:, None] + tc[None, :], _NEVER)
-    kv_pos = jnp.concatenate([ring_pos, chunk_pos], axis=1)
-    if Lp > L:
-        kv_pos = jnp.pad(kv_pos, ((0, 0), (0, Lp - L)),
-                         constant_values=_NEVER)
-
     kcat = _pad_axis1(jnp.concatenate([k_cache, k_new], axis=1), Lp)
     vcat = _pad_axis1(jnp.concatenate([v_cache, v_new], axis=1), Lp)
     qp = _pad_axis1(q, Cp)
 
     out = K.ring_chunk_attention_fwd(
         _to_head_major(qp), _to_head_major(kcat), _to_head_major(vcat),
-        q_pos, kv_pos, ring=W, window=window, softcap=softcap,
-        block_q=bq, block_kv=bkv, hq_per_kv=G,
-        interpret=_resolve_interpret(interpret))
+        pos, n_tokens, ring=W, window=window, softcap=softcap,
+        block_q=bq, block_kv=bkv, hq_per_kv=G, interpret=interpret)
     return _from_head_major(out, B, Hq)[:, :C]

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels import resolve_interpret
 
 
 def _chunk_scan(a, b):
@@ -54,7 +54,7 @@ def _lru_kernel(a_ref, b_ref, h_ref, carry_scr, *, n_s):
     carry_scr[...] = h[-1:, :]              # (1, bw) final state of the chunk
 
 
-def lru_scan(a, b, *, block_s=256, block_w=512, interpret=True):
+def lru_scan(a, b, *, block_s=256, block_w=512, interpret=None):
     """a, b: (B, S, W) -> h: (B, S, W) (f32 out).  Single-pass chunked scan."""
     B, S, W = a.shape
     bs = min(block_s, S)
@@ -72,8 +72,8 @@ def lru_scan(a, b, *, block_s=256, block_w=512, interpret=True):
         out_specs=pl.BlockSpec((1, bs, bw), lambda ib, iw, js: (ib, js, iw)),
         out_shape=jax.ShapeDtypeStruct((B, S, W), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, b)
     return h

@@ -17,7 +17,7 @@ from repro.kernels.rglru_scan.kernel import lru_scan
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def lru(a, b, block_s=256, block_w=512, interpret=True):
+def lru(a, b, block_s=256, block_w=512, interpret=None):
     """h_t = a_t h_{t-1} + b_t over axis 1.  a, b: (B, S, W)."""
     return lru_scan(a, b, block_s=block_s, block_w=block_w,
                     interpret=interpret)

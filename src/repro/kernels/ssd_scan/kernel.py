@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -69,7 +69,7 @@ def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, hT_ref, state_scr, *,
         hT_ref[0] = state_scr[...]
 
 
-def ssd_scan(xdt, a, B_, C_, *, chunk=128, hq_per_group=1, interpret=True):
+def ssd_scan(xdt, a, B_, C_, *, chunk=128, hq_per_group=1, interpret=None):
     """xdt: (BH, S, P) dt-weighted inputs; a: (BH, S) log-decays;
     B_/C_: (BG, S, N) with BH = BG * hq_per_group.
 
@@ -100,8 +100,8 @@ def ssd_scan(xdt, a, B_, C_, *, chunk=128, hq_per_group=1, interpret=True):
             jax.ShapeDtypeStruct((BH, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xdt, a, B_, C_)
     return y, hT

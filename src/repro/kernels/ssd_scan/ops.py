@@ -39,7 +39,7 @@ def _ssd_pallas(x, dt, A, B_, C_, chunk, interpret):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def ssd_with_state(x, dt, A, B_, C_, chunk=128, interpret=True):
+def ssd_with_state(x, dt, A, B_, C_, chunk=128, interpret=None):
     """x: (B,S,H,P); dt: (B,S,H); A: (H,); B_/C_: (B,S,G,N).
 
     Returns (y (B,S,H,P), h_final (B,H,P,N) f32)."""
@@ -67,6 +67,6 @@ def _bwd(chunk, interpret, res, cts):
 ssd_with_state.defvjp(_fwd, _bwd)
 
 
-def ssd(x, dt, A, B_, C_, chunk=128, interpret=True):
+def ssd(x, dt, A, B_, C_, chunk=128, interpret=None):
     """Sequence output only."""
     return ssd_with_state(x, dt, A, B_, C_, chunk, interpret)[0]

@@ -13,17 +13,14 @@ locality-aware device order — comes from ``repro.core.layout.Layout``.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    # jax < 0.5 has no AxisType / axis_types kwarg (Auto is the default)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def batch_axes(mesh) -> tuple:

@@ -15,7 +15,9 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
@@ -300,7 +302,6 @@ def extract_pool_entries(pool, spec: CacheViewSpec, blocks,
     state leaves when ``state_slot`` is None).  On a real fleet this is the
     D2H DMA of exactly the stream's used pages; ``insert_pool_entries`` is
     its inverse."""
-    import numpy as np
     blk = jnp.asarray(list(blocks), jnp.int32)
     out = []
     for leaf, s in zip(jax.tree.leaves(pool), spec.leaves):
@@ -413,21 +414,18 @@ def scatter_pool_rows(pool, spec: CacheViewSpec, blocks, leaves,
     return jax.tree.unflatten(spec.treedef, out)
 
 
-def place_block_pool(pool, spec: CacheViewSpec, devices=None):
+def place_block_pool(pool, spec: CacheViewSpec, devices):
     """Commit pool storage onto physical devices — the placement half of
     the two-tier hierarchy.
 
-    Single device (CPU CI, one-chip dev box): a committed ``device_put``
-    — placement is explicit rather than inherited from whatever the first
+    Single device (CPU CI, one chip): a committed ``device_put`` —
+    placement is explicit rather than inherited from whatever the first
     jit happened to choose.  Multiple devices: shard every leaf's
-    block/slot axis across the chiplet group's devices when it divides
-    evenly (domain block-id ranges are contiguous, so each group's pages
-    land on its own devices), replicating leaves that don't divide."""
-    devices = list(devices if devices is not None else jax.devices())
+    block/slot axis across them when it divides evenly (the pool pads
+    both axes so it does; domain block-id ranges are contiguous, so each
+    group's pages land on few devices), replicating leaves that don't."""
     if len(devices) <= 1:
         return jax.device_put(pool, devices[0])
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec
-    import numpy as np
     mesh = Mesh(np.array(devices), ("groups",))
     out = []
     for leaf, s in zip(jax.tree.leaves(pool), spec.leaves):
@@ -438,6 +436,29 @@ def place_block_pool(pool, spec: CacheViewSpec, devices=None):
             ps = PartitionSpec()
         out.append(jax.device_put(leaf, NamedSharding(mesh, ps)))
     return jax.tree.unflatten(spec.treedef, out)
+
+
+def place_params(params, devices):
+    """Commit params onto the serving devices: a committed ``device_put``
+    on one device, replicated over several (each device's share of the
+    pool is then read by a step running against its own copy)."""
+    if len(devices) <= 1:
+        return jax.device_put(params, devices[0])
+    mesh = Mesh(np.array(devices), ("groups",))
+    return jax.device_put(params, NamedSharding(mesh, PartitionSpec()))
+
+
+def replicate_over(fn, devices):
+    """``fn`` inside a program that spans ``devices``, run whole on every
+    device (``shard_map`` with every operand and result replicated): XLA
+    cannot partition a Mosaic (Pallas TPU) kernel by itself, so a step
+    that calls one over several devices must be placed by hand.  One
+    device: ``fn`` unchanged."""
+    if len(devices) <= 1:
+        return fn
+    mesh = Mesh(np.array(devices), ("groups",))
+    return jax.shard_map(fn, mesh=mesh, in_specs=PartitionSpec(),
+                         out_specs=PartitionSpec(), check_vma=False)
 
 
 def select_streams(spec: CacheViewSpec, mask, new_cache, old_cache):

@@ -38,7 +38,7 @@ from repro.runtime.failure import (FailureInjector, SimulatedFailure,
 @dataclasses.dataclass
 class TrainerConfig:
     steps: int = 100
-    ckpt_every: int = 50
+    ckpt_every: int = 50               # 0 = never checkpoint
     ckpt_dir: str = "/tmp/repro_ckpt"
     keep: int = 3
     microbatches: int = 1
@@ -64,7 +64,8 @@ class Trainer:
         self.log = log
         self.counters = PerfCounters()
         self.straggler = StragglerDetector()
-        self.ckpt = CheckpointManager(tcfg.ckpt_dir, keep=tcfg.keep)
+        self.ckpt = (CheckpointManager(tcfg.ckpt_dir, keep=tcfg.keep)
+                     if tcfg.ckpt_every else None)
         self.scheduler = None
         if tcfg.arcas and topology is not None:
             self.scheduler = GlobalScheduler(
@@ -80,8 +81,10 @@ class Trainer:
         self.pspecs = shlib.param_specs(cfg, mesh, fsdp=fsdp)
         self.psh = shlib.named(mesh, self.pspecs)
         key = jax.random.PRNGKey(self.tcfg.seed)
-        params_host = init_params(cfg, key)
-        self.params = jax.device_put(params_host, self.psh)
+        # drawn straight into the mesh's shardings: no device ever holds
+        # the whole unsharded model
+        self.params = jax.jit(init_params, static_argnums=0,
+                              out_shardings=self.psh)(cfg, key)
         self.opt_state = init_opt_state(self.params)
         ospecs = shlib.opt_specs(cfg, mesh, self.pspecs)
         self.osh = shlib.named(mesh, ospecs)
@@ -152,7 +155,7 @@ class Trainer:
 
     # ------------------------------------------------------------------
     def resume_if_possible(self) -> bool:
-        latest = self.ckpt.latest()
+        latest = self.ckpt.latest() if self.ckpt is not None else None
         if latest is None:
             return False
         state = {"params": self.params, "opt": self.opt_state}
@@ -198,15 +201,12 @@ class Trainer:
             self.step += 1
 
             if self._hlo_bytes is None:
-                try:
-                    # pull collective constants from the compiled step once
-                    args = (self.params, self.opt_state, batch)
-                    if self.tcfg.compress_cross_pod:
-                        args += (self._ef,)
-                    txt = self._jit_step.lower(*args).compile().as_text()
-                    self._collective_feed(txt)
-                except Exception:   # noqa: BLE001
-                    self._hlo_bytes = {"remote": 0.0, "local": 0.0}
+                # pull collective constants from the compiled step once
+                args = (self.params, self.opt_state, batch)
+                if self.tcfg.compress_cross_pod:
+                    args += (self._ef,)
+                txt = self._jit_step.lower(*args).compile().as_text()
+                self._collective_feed(txt)
 
             slow = self.straggler.observe(dt)
             self.counters.record_step(
@@ -221,13 +221,16 @@ class Trainer:
             if self.step % self.tcfg.log_every == 0:
                 self.log(f"[trainer] step {self.step} loss {loss:.4f} "
                          f"({dt*1e3:.0f} ms)")
-            if self.step % self.tcfg.ckpt_every == 0 or self.step == steps:
+            if self.ckpt is not None and (
+                    self.step % self.tcfg.ckpt_every == 0
+                    or self.step == steps):
                 self.ckpt.save(
                     self.step,
                     {"params": self.params, "opt": self.opt_state},
                     metadata={"loader": self.loader.state_dict()},
                     blocking=not self.tcfg.async_ckpt)
-        self.ckpt.wait()
+        if self.ckpt is not None:
+            self.ckpt.wait()
         return {"losses": losses, "steps": self.step,
                 "wall": time.monotonic() - t_train0,
                 "straggler_events": list(self.straggler.events),

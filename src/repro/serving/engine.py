@@ -79,10 +79,11 @@ PR-1 slot monolith.  Both ride the same token loop — their streams simply
 never have more than one token per tick — and stay token-identical to the
 lazy path.
 
-On this CPU container the model compute is real (tiny configs) while the
-replica groups are logical queues over the same device — the scheduling,
-batching, stealing, paging, growth, controller and migration behavior is
-exactly the code a TPU deployment would run host-side.
+Replica groups are logical queues over the engine's devices
+(``ServeEngine(devices=)``, default every visible device): params are
+committed to each device (replicated when there are several) and the
+pool's pages are sharded across them, while the scheduling, batching,
+stealing, paging, growth, controller and migration logic runs host-side.
 """
 from __future__ import annotations
 
@@ -369,7 +370,7 @@ class _Group:
 class ServeEngine:
     def __init__(self, cfg: ModelConfig, topology: ChipletTopology,
                  ecfg: EngineConfig = EngineConfig(), *, seed: int = 0,
-                 spread_rate: int = 1):
+                 spread_rate: int = 1, devices=None):
         self.cfg = cfg
         self.topology = topology
         self.ecfg = ecfg
@@ -380,7 +381,10 @@ class ServeEngine:
         self.counters = self.sched.counters
         self.controller = self.sched.controller
         self.runtime = self.sched.tasks
-        self.params = init_params(cfg, jax.random.PRNGKey(seed))
+        self.devices = list(devices if devices is not None
+                            else jax.devices())
+        self.params = dec.place_params(
+            init_params(cfg, jax.random.PRNGKey(seed)), self.devices)
         self._prefill = jax.jit(make_prefill(cfg, max_len=ecfg.max_len))
         self._decode = jax.jit(make_serve_step(cfg))
         self._rid = itertools.count()
@@ -438,7 +442,7 @@ class ServeEngine:
                 cfg, n_domains=topology.total_groups, max_len=ecfg.max_len,
                 block_tokens=ecfg.block_tokens, counters=self.counters,
                 retention=ecfg.cached_retention, topology=topology,
-                **budget)
+                devices=self.devices, **budget)
             self.waiters = WaitQueue(self.runtime)
             # wake ONE waiter per free: grants stay FIFO (a successful
             # admission cascades the wake to the next waiter itself).
@@ -938,8 +942,10 @@ class ServeEngine:
         ``mode="parallel"`` compiles the fused multi-token forward (one
         model pass per tick); "scan" the per-token reference."""
         spec = self.pool.spec
-        step = make_serve_chunk_step(self.cfg, spec, mode=mode,
-                                     chunk_kernel=self._chunk_kernel)
+        step = dec.replicate_over(
+            make_serve_chunk_step(self.cfg, spec, mode=mode,
+                                  chunk_kernel=self._chunk_kernel),
+            self.devices)
 
         def paged_chunk(params, storage, tables, state_slots, tokens, pos,
                         n_tokens):
@@ -959,8 +965,10 @@ class ServeEngine:
         optimistically; the host rolls back rejected suffixes from the
         pool's page checkpoints."""
         spec = self.pool.spec
-        step = make_spec_verify_step(self.cfg, spec, mode=mode,
-                                     chunk_kernel=self._chunk_kernel)
+        step = dec.replicate_over(
+            make_spec_verify_step(self.cfg, spec, mode=mode,
+                                  chunk_kernel=self._chunk_kernel),
+            self.devices)
 
         def paged_spec(params, storage, tables, state_slots, tokens, pos,
                        n_tokens):

@@ -71,6 +71,7 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, \
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, ShapeConfig
@@ -174,7 +175,7 @@ class KVBlockPool:
                  block_tokens: int = 16,
                  counters: Optional[PerfCounters] = None,
                  retention: str = "access",
-                 topology=None):
+                 topology=None, devices=None):
         if retention not in ("access", "blind"):
             raise ValueError(f"unknown retention policy {retention!r}")
         self.cfg = cfg
@@ -208,18 +209,23 @@ class KVBlockPool:
             list(range(1 + d * self.states_per_domain,
                        1 + (d + 1) * self.states_per_domain))
             for d in range(n_domains)]
+        # physical placement: the pool is committed onto ``devices``
+        # (default: every visible device; one device — CPU CI, one chip —
+        # commits everything there).  Over several devices the block and
+        # state axes shard evenly: both are padded to a multiple of the
+        # device count with ids no domain ever grants, and domain id
+        # ranges are contiguous, so each domain's pages sit on few
+        # devices.  ``topology`` is advisory: the split follows the
+        # devices either way.
+        devices = list(devices if devices is not None else jax.devices())
+        k = len(devices)
         self.storage = dec.init_block_pool(
             cfg, self.spec,
-            n_blocks=1 + n_domains * self.blocks_per_domain,
-            n_states=1 + n_domains * self.states_per_domain,
+            n_blocks=-(-(1 + n_domains * self.blocks_per_domain) // k) * k,
+            n_states=-(-(1 + n_domains * self.states_per_domain) // k) * k,
             block_tokens=self.block_tokens, max_len=max_len)
-        # physical placement: commit the pool onto its chiplet group's
-        # devices (domain block-id ranges are contiguous so an even shard
-        # of the block axis IS the per-group split; one device — CPU CI —
-        # commits everything there).  ``topology`` is advisory: the split
-        # follows the visible jax devices either way.
         self.topology = topology
-        self.storage = dec.place_block_pool(self.storage, self.spec)
+        self.storage = dec.place_block_pool(self.storage, self.spec, devices)
         self._on_free: List[Callable[[], None]] = []
         # swap tier: D2H/H2D copies of a table's used pages + state slot,
         # landing in preallocated (pinned where the platform has it) host

@@ -9,11 +9,12 @@ Two pieces make the pool's second tier physical instead of ad-hoc:
     per spilled stream) instead of fresh numpy allocations per spill —
     on real hardware these are the pinned staging buffers D2H DMA
     requires; the tier probes whether the platform exposes a
-    ``pinned_host`` memory space and records the answer (TPU yes, CPU CI
-    no — plain numpy there, same layout).  A first-fit extent allocator
-    keeps page ranges contiguous so a landed spill is one slice view per
-    leaf, and an overflow path falls back to ad-hoc arrays (counted)
-    when the preallocation is exhausted rather than failing the spill.
+    ``pinned_host`` memory space and records the answer (TPU and, since
+    JAX 0.5, CPU both do; the buffers are plain numpy either way).  A
+    first-fit extent allocator keeps page ranges contiguous so a landed
+    spill is one slice view per leaf, and an overflow path falls back to
+    ad-hoc arrays (counted) when the preallocation is exhausted rather
+    than failing the spill.
 
 ``InFlightSpill``
     One issued-but-unfenced D2H copy.  ``KVBlockPool.spill_issue``
@@ -38,13 +39,10 @@ import jax
 
 def pinned_host_available() -> bool:
     """Probe whether the default device exposes a ``pinned_host`` memory
-    space (TPU runtimes do; CPU does not)."""
-    try:
-        dev = jax.devices()[0]
-        return any(m.kind == "pinned_host"
-                   for m in dev.addressable_memories())
-    except Exception:
-        return False
+    space.  A device without one answers False; any failure of the probe
+    itself propagates."""
+    return any(m.kind == "pinned_host"
+               for m in jax.devices()[0].addressable_memories())
 
 
 @dataclasses.dataclass

@@ -16,7 +16,9 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def _run_sub(code: str, devices: int = 8) -> str:
-    env = dict(os.environ,
+    # the child runs on virtual CPU devices, never on an accelerator the
+    # parent may hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                PYTHONPATH=SRC)
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
@@ -67,11 +69,10 @@ def test_roofline_math():
 def test_nested_while_trip_counts_subprocess():
     out = _run_sub("""
         import jax, jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.launch.hlo_analysis import collective_bytes
-        at = getattr(jax.sharding, "AxisType", None)
-        kw = {"axis_types": (at.Auto,)*2} if at is not None else {}
-        mesh = jax.make_mesh((4, 2), ("data", "model"), **kw)
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         W = jax.ShapeDtypeStruct((64, 64), jnp.float32,
                                  sharding=NamedSharding(mesh, P(None, "model")))
         x = jax.ShapeDtypeStruct((8, 64), jnp.float32,
