@@ -50,8 +50,7 @@ def main():
     toks, _, _ = gen(trainer.params, cache, first, pos, jax.random.PRNGKey(0))
     print("generated tokens:", np.asarray(toks)[0].tolist())
     print("ARCAS counters:", {k: round(v, 1) for k, v in
-                              trainer.counters.snapshot().items()
-                              if not k.startswith("segment")})
+                              trainer.counters.snapshot().items()})
 
 
 if __name__ == "__main__":

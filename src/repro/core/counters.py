@@ -8,27 +8,35 @@ Counter names mirror Tab. 1/2 of the paper:
                   this is what Algorithm 1 thresholds on)
   dcn_bytes     — cross-pod traffic (the "main memory" analogue)
 
-Counters are cheap (plain floats), support scoped segments (the paper's
-"profile only specific code segments"), and keep a ring buffer of recent
-step samples for rate estimation.
+Counters are cheap (plain floats) and keep a ring buffer of recent step
+samples.  ``span`` marks a stretch of host work (the paper's "profile only
+specific code segments") in JAX's profiler trace, on the same clock as the
+device's operations; it costs about a microsecond while no profiler runs.
 """
 from __future__ import annotations
 
 import collections
-import contextlib
 import dataclasses
 import time
-from typing import Deque, Dict, Optional
+from typing import Deque, Dict
+
+from jax.profiler import TraceAnnotation
+
+
+def span(name: str, **args) -> TraceAnnotation:
+    """A host span for JAX's profiler trace: ``with span("arcas.commit"):``.
+    Names start with ``arcas.``; ``args`` identify a request or a step."""
+    return TraceAnnotation(name, **args)
 
 
 @dataclasses.dataclass
 class StepSample:
     t: float
     step_time: float
-    local_bytes: float
-    remote_bytes: float
-    dcn_bytes: float
-    flops: float
+    local_bytes: float = 0.0
+    remote_bytes: float = 0.0
+    dcn_bytes: float = 0.0
+    flops: float = 0.0
     # KV block-pool health (serving): fraction of pool blocks in use, parks
     # (allocation failures) since the previous sample, and blocks copied
     # between chiplet-group domains since the previous sample.
@@ -106,56 +114,14 @@ class PerfCounters:
         occupancy)."""
         self.totals[name] = value
 
-    def record_step(self, *, step_time: float, local_bytes: float = 0.0,
-                    remote_bytes: float = 0.0, dcn_bytes: float = 0.0,
-                    flops: float = 0.0, kv_occupancy: float = 0.0,
-                    kv_parks: float = 0.0, kv_blocks_migrated: float = 0.0,
-                    kv_lazy_grows: float = 0.0,
-                    kv_mid_decode_parks: float = 0.0,
-                    prefill_chunks: float = 0.0,
-                    kv_spilled_pages: float = 0.0,
-                    kv_restores: float = 0.0,
-                    recompute_tokens: float = 0.0,
-                    mixed_tick_decode_rows_saved: float = 0.0,
-                    kv_prefix_hits: float = 0.0,
-                    prefill_tokens_skipped: float = 0.0,
-                    kv_shared_pages: float = 0.0,
-                    kv_shared_bytes: float = 0.0,
-                    spec_tokens_drafted: float = 0.0,
-                    spec_tokens_accepted: float = 0.0,
-                    spec_rollbacks: float = 0.0,
-                    spec_accept_rate: float = 0.0,
-                    kv_bypass_grants: float = 0.0,
-                    kv_head_wait_ticks: float = 0.0,
-                    kv_spill_inflight_pages: float = 0.0,
-                    kv_spill_inflight_bytes: float = 0.0,
-                    kv_ticks_while_inflight: float = 0.0,
-                    kv_fence_waits: float = 0.0):
+    def record_step(self, *, step_time: float, **fields: float):
+        """Append one ``StepSample``; ``fields`` are its named fields (an
+        unknown name raises)."""
+        sample = StepSample(self._clock(), step_time, **fields)
         self.add("steps", 1)
-        self.add("local_bytes", local_bytes)
-        self.add("remote_bytes", remote_bytes)
-        self.add("dcn_bytes", dcn_bytes)
-        self.add("flops", flops)
-        self.samples.append(StepSample(self._clock(), step_time, local_bytes,
-                                       remote_bytes, dcn_bytes, flops,
-                                       kv_occupancy, kv_parks,
-                                       kv_blocks_migrated, kv_lazy_grows,
-                                       kv_mid_decode_parks, prefill_chunks,
-                                       kv_spilled_pages, kv_restores,
-                                       recompute_tokens,
-                                       mixed_tick_decode_rows_saved,
-                                       kv_prefix_hits,
-                                       prefill_tokens_skipped,
-                                       kv_shared_pages, kv_shared_bytes,
-                                       spec_tokens_drafted,
-                                       spec_tokens_accepted,
-                                       spec_rollbacks, spec_accept_rate,
-                                       kv_bypass_grants,
-                                       kv_head_wait_ticks,
-                                       kv_spill_inflight_pages,
-                                       kv_spill_inflight_bytes,
-                                       kv_ticks_while_inflight,
-                                       kv_fence_waits))
+        for name in ("local_bytes", "remote_bytes", "dcn_bytes", "flops"):
+            self.add(name, getattr(sample, name))
+        self.samples.append(sample)
 
     # -- Algorithm 1 inputs ---------------------------------------------------
     def event_counter(self, name: str = "remote_bytes") -> float:
@@ -170,37 +136,6 @@ class PerfCounters:
 
     def mark_time(self):
         self._last_reset = self._clock()
-
-    # -- derived metrics ------------------------------------------------------
-    def ema_step_time(self, alpha: float = 0.25) -> Optional[float]:
-        if not self.samples:
-            return None
-        ema = self.samples[0].step_time
-        for s in self.samples:
-            ema = alpha * s.step_time + (1 - alpha) * ema
-        return ema
-
-    def rates(self) -> Dict[str, float]:
-        if len(self.samples) < 2:
-            return {}
-        dt = max(self.samples[-1].t - self.samples[0].t, 1e-9)
-        n = len(self.samples)
-        return {
-            "steps_per_s": n / dt,
-            "remote_bytes_per_s": sum(s.remote_bytes for s in self.samples) / dt,
-            "local_bytes_per_s": sum(s.local_bytes for s in self.samples) / dt,
-            "dcn_bytes_per_s": sum(s.dcn_bytes for s in self.samples) / dt,
-        }
-
-    # -- scoped segment profiling (paper: "monitor only specific segments") ---
-    @contextlib.contextmanager
-    def segment(self, name: str):
-        t0 = self._clock()
-        try:
-            yield
-        finally:
-            self.add(f"segment/{name}/time", self._clock() - t0)
-            self.add(f"segment/{name}/calls", 1)
 
     def snapshot(self) -> Dict[str, float]:
         return dict(self.totals)

@@ -28,7 +28,7 @@ from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, \
 import jax
 
 from repro.core.controller import AdaptiveController, ControllerConfig, Decision
-from repro.core.counters import PerfCounters
+from repro.core.counters import PerfCounters, span
 from repro.core.layout import Layout
 from repro.core.tasks import TaskRuntime
 from repro.core.topology import ChipletTopology
@@ -93,42 +93,11 @@ class GlobalScheduler:
         """
         self._step += 1
         if step_metrics:
-            self.counters.record_step(
-                step_time=step_metrics.get("step_time", 0.0),
-                local_bytes=step_metrics.get("local_bytes", 0.0),
-                remote_bytes=step_metrics.get("remote_bytes", 0.0),
-                dcn_bytes=step_metrics.get("dcn_bytes", 0.0),
-                flops=step_metrics.get("flops", 0.0),
-                kv_occupancy=step_metrics.get("kv_occupancy", 0.0),
-                kv_parks=step_metrics.get("kv_parks", 0.0),
-                kv_blocks_migrated=step_metrics.get("kv_blocks_migrated",
-                                                    0.0),
-                kv_lazy_grows=step_metrics.get("kv_lazy_grows", 0.0),
-                kv_mid_decode_parks=step_metrics.get("kv_mid_decode_parks",
-                                                     0.0),
-                prefill_chunks=step_metrics.get("prefill_chunks", 0.0),
-                kv_spilled_pages=step_metrics.get("kv_spilled_pages", 0.0),
-                kv_restores=step_metrics.get("kv_restores", 0.0),
-                recompute_tokens=step_metrics.get("recompute_tokens", 0.0),
-                mixed_tick_decode_rows_saved=step_metrics.get(
-                    "mixed_tick_decode_rows_saved", 0.0),
-                kv_prefix_hits=step_metrics.get("kv_prefix_hits", 0.0),
-                prefill_tokens_skipped=step_metrics.get(
-                    "prefill_tokens_skipped", 0.0),
-                kv_shared_pages=step_metrics.get("kv_shared_pages", 0.0),
-                kv_shared_bytes=step_metrics.get("kv_shared_bytes", 0.0),
-                spec_tokens_drafted=step_metrics.get("spec_tokens_drafted",
-                                                     0.0),
-                spec_tokens_accepted=step_metrics.get(
-                    "spec_tokens_accepted", 0.0),
-                spec_rollbacks=step_metrics.get("spec_rollbacks", 0.0),
-                spec_accept_rate=step_metrics.get("spec_accept_rate", 0.0),
-                kv_bypass_grants=step_metrics.get("kv_bypass_grants", 0.0),
-                kv_head_wait_ticks=step_metrics.get("kv_head_wait_ticks",
-                                                    0.0))
+            self.counters.record_step(**step_metrics)
         self.last_active = (self.tasks.tick()
                             if run_tasks and self.tasks.pending() else 0)
-        return self._control()
+        with span("arcas.round_metrics"):
+            return self._control()
 
     def _control(self) -> Optional[Decision]:
         if not self.control_enabled:
@@ -162,11 +131,16 @@ class GlobalScheduler:
         """
         rounds = 0
         while self.tasks.pending() and rounds < max_rounds:
-            self.tick(step_metrics=metrics_fn() if metrics_fn else None)
-            if concurrency_trace is not None:
-                concurrency_trace.append(self.last_active)
-            if round_hook is not None:
-                round_hook()
+            with span("arcas.round"):
+                with span("arcas.round_metrics"):
+                    sample = metrics_fn() if metrics_fn else None
+                    if sample:
+                        self.counters.record_step(**sample)
+                self.tick()
+                if concurrency_trace is not None:
+                    concurrency_trace.append(self.last_active)
+                if round_hook is not None:
+                    round_hook()
             rounds += 1
         if self.tasks.pending():
             raise RuntimeError("GlobalScheduler.run_until_done exceeded "

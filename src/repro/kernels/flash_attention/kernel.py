@@ -276,6 +276,7 @@ def ring_chunk_attention_fwd(q, k, v, pos, n_tokens, *, ring, window=0,
 
     out = pl.pallas_call(
         kernel,
+        name="ring_chunk_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(BH, n_q, n_kv),

@@ -99,6 +99,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.controller import ControllerConfig, Decision
+from repro.core.counters import span
 from repro.core.layout import Layout
 from repro.core.scheduler import GlobalScheduler, TieredQueues
 from repro.core.tasks import BLOCK, WaitQueue
@@ -784,35 +785,37 @@ class ServeEngine:
         # lazy: only the first chunk's pages are committed at admission
         first = (min(self._chunk, max(1, len(req.prompt)))
                  if self._lazy else None)
-        if self._share and req.page_keys is None:
-            req.page_keys = self.pool.prefix_keys(req.prompt)
         while True:
-            if self.waiters.oldest() is cell["task"]:
-                g, table = self._try_admit(total, first, req.page_keys,
-                                           len(req.prompt))
+            with span("arcas.admit", rid=req.rid):
+                if self._share and req.page_keys is None:
+                    req.page_keys = self.pool.prefix_keys(req.prompt)
+                table = None
+                if self.waiters.oldest() is cell["task"]:
+                    g, table = self._try_admit(total, first, req.page_keys,
+                                               len(req.prompt))
+                elif (self._bypass_on
+                        and cell["task"].id in self._bypass_cells
+                        and self._aging_clear(cell["task"])):
+                    g, table = self._try_bypass(req, total)
+                    if table is not None:
+                        req.bypassed = True
+                        self.counters.add("kv_bypass_grants", 1)
+                        self.counters.add(f"kv_class_bypass/{req.cls}", 1)
                 if table is not None:
-                    break
-            elif (self._bypass_on
-                    and cell["task"].id in self._bypass_cells
-                    and self._aging_clear(cell["task"])):
-                g, table = self._try_bypass(req, total)
-                if table is not None:
-                    req.bypassed = True
-                    self.counters.add("kv_bypass_grants", 1)
-                    self.counters.add(f"kv_class_bypass/{req.cls}", 1)
-                    break
+                    self._leave_line(cell["task"])
+                    req.grant_rounds.append(self._round)
+                    self.counters.add(f"kv_class_admits/{req.cls}", 1)
+                    req.table = table
+                    # shared prefix pages are already filled: prefill
+                    # resumes at the first unmatched chunk boundary
+                    # (identical to a restored park)
+                    req.prefix_tokens = (table.used_pages
+                                         * self.pool.block_tokens)
+                    req.group = g.gid
+                    self.queues.push(g.gid, req)
+                    return
             yield BLOCK                 # woken by KVBlockPool.free (heads
                                         # + bypass candidates) or a grant
-        self._leave_line(cell["task"])
-        req.grant_rounds.append(self._round)
-        self.counters.add(f"kv_class_admits/{req.cls}", 1)
-        req.table = table
-        # shared prefix pages are already filled: prefill resumes at the
-        # first unmatched chunk boundary (identical to a restored park)
-        req.prefix_tokens = table.used_pages * self.pool.block_tokens
-        req.group = g.gid
-        self.queues.push(g.gid, req)
-        return
 
     def open_loop_client(self, schedule: Iterable[Tuple[int, np.ndarray, int]]
                          ) -> Any:
@@ -1151,6 +1154,12 @@ class ServeEngine:
         loses the least work and nobody behind it in the line exists)."""
         if self.pool is None:
             return
+        with span("arcas.stall"):
+            self._relieve_stall()
+
+    def _relieve_stall(self):
+        """One round of the pressure ladder: poll transfers, spill past a
+        watermark, and break an allocation stall."""
         self._round += 1
         if self._async:
             # poll phase of the ladder: land every transfer whose device
@@ -1352,52 +1361,57 @@ class ServeEngine:
     def _admit(self, g: _Group):
         for slot in g.free_slots():
             if g.resume:                       # migrated streams first
-                self._install(g, slot, g.resume.pop(0))
+                fl = g.resume.pop(0)
+                with span("arcas.admit", rid=fl.req.rid):
+                    self._install(g, slot, fl)
                 continue
             req, tier = self.queues.pop(g.gid, accept=self._accept_steal(g))
             if req is None:
                 break
-            if tier != "local":
-                req.group = g.gid
-            if self._lazy:
-                # the token loop prefills this stream chunk-by-chunk;
-                # admission points a slot at the first unmatched prompt
-                # position (0 when no prefix pages were shared)
-                g.slots[slot] = req
-                g.pos_h[slot] = req.prefix_tokens
-                g.tok_h[slot] = 0
-                continue
-            prompt = req.prompt[None, :]
-            logits, cache1 = self._prefill(self.params, {"tokens": prompt})
-            nxt = int(jnp.argmax(logits[0]))
-            req.generated.append(nxt)
-            req.t_first = self._clock()
-            self.counters.add("prefills", 1)
-            self.counters.add("tokens_processed", len(req.prompt))
-            if len(req.generated) >= req.max_new:
-                # prefill's token already met the budget (max_new=1):
-                # finish without ever taking a decode slot or pool pages
-                req.t_done = req.t_first
-                self._inflight -= 1
+            with span("arcas.admit", rid=req.rid):
+                if tier != "local":
+                    req.group = g.gid
+                if self._lazy:
+                    # the token loop prefills this stream chunk-by-chunk;
+                    # admission points a slot at the first unmatched prompt
+                    # position (0 when no prefix pages were shared)
+                    g.slots[slot] = req
+                    g.pos_h[slot] = req.prefix_tokens
+                    g.tok_h[slot] = 0
+                    continue
+                prompt = req.prompt[None, :]
+                logits, cache1 = self._prefill(self.params, {"tokens": prompt})
+                nxt = int(jnp.argmax(logits[0]))
+                req.generated.append(nxt)
+                req.t_first = self._clock()
+                self.counters.add("prefills", 1)
+                self.counters.add("tokens_processed", len(req.prompt))
+                if len(req.generated) >= req.max_new:
+                    # prefill's token already met the budget (max_new=1):
+                    # finish without ever taking a decode slot or pool pages
+                    req.t_done = req.t_first
+                    self._inflight -= 1
+                    if self.ecfg.paged:
+                        self.pool.free(req.table)
+                    continue
                 if self.ecfg.paged:
-                    self.pool.free(req.table)
-                continue
-            if self.ecfg.paged:
-                tables, slots1 = self._table_row(req)
-                self.pool.storage = self._commit_prefill(
-                    self.pool.storage,
-                    jnp.asarray(np.asarray([tables], np.int32)),
-                    jnp.asarray(np.asarray([slots1], np.int32)), cache1)
-                req.table.used_pages = self.pool.pages_needed(
-                    len(req.prompt))
-            else:
-                # copy the single-stream cache into the group slot
-                g.cache = jax.tree.map(
-                    lambda pool, one: pool.at[:, slot].set(one[:, 0]),
-                    g.cache, cache1)
-            g.slots[slot] = req
-            g.pos_h[slot] = len(req.prompt)
-            g.tok_h[slot] = nxt
+                    tables, slots1 = self._table_row(req)
+                    with span("arcas.dispatch", step="commit_prefill"):
+                        self.pool.storage = self._commit_prefill(
+                            self.pool.storage,
+                            jnp.asarray(np.asarray([tables], np.int32)),
+                            jnp.asarray(np.asarray([slots1], np.int32)),
+                            cache1)
+                    req.table.used_pages = self.pool.pages_needed(
+                        len(req.prompt))
+                else:
+                    # copy the single-stream cache into the group slot
+                    g.cache = jax.tree.map(
+                        lambda pool, one: pool.at[:, slot].set(one[:, 0]),
+                        g.cache, cache1)
+                g.slots[slot] = req
+                g.pos_h[slot] = len(req.prompt)
+                g.tok_h[slot] = nxt
 
     def _split_tick(self, g: _Group, n_h, toks, C: int,
                     deco_rows: List[int]) -> np.ndarray:
@@ -1418,51 +1432,59 @@ class ServeEngine:
         """
         B = self.ecfg.max_batch
         P = self.pool.pages_per_stream
-        chunk_rows = [i for i in range(B) if n_h[i] > 1]
         # -- chunk half: compacted fused forward over prefill streams only
-        Bc = 1
-        while Bc < len(chunk_rows):
-            Bc *= 2
-        Bc = min(Bc, B)
-        rows = chunk_rows + [None] * (Bc - len(chunk_rows))
-        trows, srows = zip(*(self._table_row(g.slots[i])
-                             if i is not None else self._table_row(None)
-                             for i in rows))
-        toks_c = np.zeros((Bc, C), np.int32)
-        pos_c = np.zeros((Bc,), np.int32)
-        n_c = np.zeros((Bc,), np.int32)
-        for j, i in enumerate(chunk_rows):
-            toks_c[j] = toks[i]
-            pos_c[j] = g.pos_h[i]
-            n_c[j] = n_h[i]
-        logits_c, self.pool.storage = self._paged_chunk(
-            self.params, self.pool.storage,
-            jnp.asarray(np.asarray(trows, np.int32).reshape(Bc, P)),
-            jnp.asarray(np.asarray(srows, np.int32)),
-            jnp.asarray(toks_c), jnp.asarray(pos_c), jnp.asarray(n_c))
-        nxt_c = np.asarray(dec.next_token_ids(logits_c, jnp.asarray(n_c)))
+        with span("arcas.assemble"):
+            chunk_rows = [i for i in range(B) if n_h[i] > 1]
+            Bc = 1
+            while Bc < len(chunk_rows):
+                Bc *= 2
+            Bc = min(Bc, B)
+            rows = chunk_rows + [None] * (Bc - len(chunk_rows))
+            trows, srows = zip(*(self._table_row(g.slots[i])
+                                 if i is not None else self._table_row(None)
+                                 for i in rows))
+            toks_c = np.zeros((Bc, C), np.int32)
+            pos_c = np.zeros((Bc,), np.int32)
+            n_c = np.zeros((Bc,), np.int32)
+            for j, i in enumerate(chunk_rows):
+                toks_c[j] = toks[i]
+                pos_c[j] = g.pos_h[i]
+                n_c[j] = n_h[i]
+        with span("arcas.dispatch", step="chunk"):
+            logits_c, self.pool.storage = self._paged_chunk(
+                self.params, self.pool.storage,
+                jnp.asarray(np.asarray(trows, np.int32).reshape(Bc, P)),
+                jnp.asarray(np.asarray(srows, np.int32)),
+                jnp.asarray(toks_c), jnp.asarray(pos_c), jnp.asarray(n_c))
+        with span("arcas.sync"):
+            nxt_c = np.asarray(dec.next_token_ids(logits_c,
+                                                  jnp.asarray(n_c)))
         # -- decode half: the single-token step, compacted to its own bucket
-        Bd = 1
-        while Bd < len(deco_rows):
-            Bd *= 2
-        Bd = min(Bd, B)
-        rows_d = deco_rows + [None] * (Bd - len(deco_rows))
-        trows, srows = zip(*(self._table_row(g.slots[i])
-                             if i is not None else self._table_row(None)
-                             for i in rows_d))
-        toks_d = np.zeros((Bd, 1), np.int32)
-        pos_d = np.zeros((Bd,), np.int32)
-        n_d = np.zeros((Bd,), np.int32)
-        for j, i in enumerate(deco_rows):
-            toks_d[j, 0] = toks[i, 0]
-            pos_d[j] = g.pos_h[i]
-            n_d[j] = 1
-        logits_d, self.pool.storage = self._paged_decode(
-            self.params, self.pool.storage,
-            jnp.asarray(np.asarray(trows, np.int32).reshape(Bd, P)),
-            jnp.asarray(np.asarray(srows, np.int32)),
-            jnp.asarray(toks_d), jnp.asarray(pos_d))
-        nxt_d = np.asarray(dec.next_token_ids(logits_d, jnp.asarray(n_d)))
+        with span("arcas.assemble"):
+            Bd = 1
+            while Bd < len(deco_rows):
+                Bd *= 2
+            Bd = min(Bd, B)
+            rows_d = deco_rows + [None] * (Bd - len(deco_rows))
+            trows, srows = zip(*(self._table_row(g.slots[i])
+                                 if i is not None else self._table_row(None)
+                                 for i in rows_d))
+            toks_d = np.zeros((Bd, 1), np.int32)
+            pos_d = np.zeros((Bd,), np.int32)
+            n_d = np.zeros((Bd,), np.int32)
+            for j, i in enumerate(deco_rows):
+                toks_d[j, 0] = toks[i, 0]
+                pos_d[j] = g.pos_h[i]
+                n_d[j] = 1
+        with span("arcas.dispatch", step="decode"):
+            logits_d, self.pool.storage = self._paged_decode(
+                self.params, self.pool.storage,
+                jnp.asarray(np.asarray(trows, np.int32).reshape(Bd, P)),
+                jnp.asarray(np.asarray(srows, np.int32)),
+                jnp.asarray(toks_d), jnp.asarray(pos_d))
+        with span("arcas.sync"):
+            nxt_d = np.asarray(dec.next_token_ids(logits_d,
+                                                  jnp.asarray(n_d)))
         nxt = np.full((B,), -1, np.int32)   # idle rows keep the sentinel
         for j, i in enumerate(deco_rows):
             nxt[i] = nxt_d[j]
@@ -1508,28 +1530,31 @@ class ServeEngine:
         rows = sorted(drafts)
         W = self._spec_w
         P = self.pool.pages_per_stream
-        Bs = 1
-        while Bs < len(rows):
-            Bs *= 2
-        Bs = min(Bs, self.ecfg.max_batch)
-        rs = rows + [None] * (Bs - len(rows))
-        trows, srows = zip(*(self._table_row(g.slots[i])
-                             if i is not None else self._table_row(None)
-                             for i in rs))
-        toks_s = np.zeros((Bs, W), np.int32)
-        pos_s = np.zeros((Bs,), np.int32)
-        n_s = np.zeros((Bs,), np.int32)
-        for j, i in enumerate(rows):
-            n = int(n_h[i])
-            toks_s[j, :n] = toks[i, :n]
-            pos_s[j] = g.pos_h[i]
-            n_s[j] = n
-        lg, self.pool.storage = self._paged_spec(
-            self.params, self.pool.storage,
-            jnp.asarray(np.asarray(trows, np.int32).reshape(Bs, P)),
-            jnp.asarray(np.asarray(srows, np.int32)),
-            jnp.asarray(toks_s), jnp.asarray(pos_s), jnp.asarray(n_s))
-        lg = np.asarray(lg)
+        with span("arcas.assemble"):
+            Bs = 1
+            while Bs < len(rows):
+                Bs *= 2
+            Bs = min(Bs, self.ecfg.max_batch)
+            rs = rows + [None] * (Bs - len(rows))
+            trows, srows = zip(*(self._table_row(g.slots[i])
+                                 if i is not None else self._table_row(None)
+                                 for i in rs))
+            toks_s = np.zeros((Bs, W), np.int32)
+            pos_s = np.zeros((Bs,), np.int32)
+            n_s = np.zeros((Bs,), np.int32)
+            for j, i in enumerate(rows):
+                n = int(n_h[i])
+                toks_s[j, :n] = toks[i, :n]
+                pos_s[j] = g.pos_h[i]
+                n_s[j] = n
+        with span("arcas.dispatch", step="spec"):
+            lg, self.pool.storage = self._paged_spec(
+                self.params, self.pool.storage,
+                jnp.asarray(np.asarray(trows, np.int32).reshape(Bs, P)),
+                jnp.asarray(np.asarray(srows, np.int32)),
+                jnp.asarray(toks_s), jnp.asarray(pos_s), jnp.asarray(n_s))
+        with span("arcas.sync"):
+            lg = np.asarray(lg)
         self.counters.add("spec_verify_forwards", 1)
         self.counters.add("spec_row_forwards", len(rows))
         return {i: lg[j, :int(n_h[i])] for j, i in enumerate(rows)}
@@ -1543,26 +1568,28 @@ class ServeEngine:
         only those tokens in the first place."""
         W = self._spec_w
         P = self.pool.pages_per_stream
-        Br = 1
-        while Br < len(rows):
-            Br *= 2
-        Br = min(Br, self.ecfg.max_batch)
-        rs = rows + [(None, 0)] * (Br - len(rows))
-        trows, srows = zip(*(self._table_row(g.slots[i])
-                             if i is not None else self._table_row(None)
-                             for i, _ in rs))
-        toks_r = np.zeros((Br, W), np.int32)
-        pos_r = np.zeros((Br,), np.int32)
-        n_r = np.zeros((Br,), np.int32)
-        for j, (i, nc) in enumerate(rows):
-            toks_r[j, :nc] = toks[i, :nc]
-            pos_r[j] = g.pos_h[i]
-            n_r[j] = nc
-        _, self.pool.storage = self._paged_chunk(
-            self.params, self.pool.storage,
-            jnp.asarray(np.asarray(trows, np.int32).reshape(Br, P)),
-            jnp.asarray(np.asarray(srows, np.int32)),
-            jnp.asarray(toks_r), jnp.asarray(pos_r), jnp.asarray(n_r))
+        with span("arcas.assemble"):
+            Br = 1
+            while Br < len(rows):
+                Br *= 2
+            Br = min(Br, self.ecfg.max_batch)
+            rs = rows + [(None, 0)] * (Br - len(rows))
+            trows, srows = zip(*(self._table_row(g.slots[i])
+                                 if i is not None else self._table_row(None)
+                                 for i, _ in rs))
+            toks_r = np.zeros((Br, W), np.int32)
+            pos_r = np.zeros((Br,), np.int32)
+            n_r = np.zeros((Br,), np.int32)
+            for j, (i, nc) in enumerate(rows):
+                toks_r[j, :nc] = toks[i, :nc]
+                pos_r[j] = g.pos_h[i]
+                n_r[j] = nc
+        with span("arcas.dispatch", step="chunk"):
+            _, self.pool.storage = self._paged_chunk(
+                self.params, self.pool.storage,
+                jnp.asarray(np.asarray(trows, np.int32).reshape(Br, P)),
+                jnp.asarray(np.asarray(srows, np.int32)),
+                jnp.asarray(toks_r), jnp.asarray(pos_r), jnp.asarray(n_r))
         self.counters.add("spec_reapply_forwards", 1)
         self.counters.add("spec_row_reapplies", len(rows))
 
@@ -1573,93 +1600,95 @@ class ServeEngine:
         drafted tokens when speculative decoding is on) for decode
         streams.  Lazy tables grow (or park their stream) before the step
         commits any bytes."""
-        B = self.ecfg.max_batch
-        n_h = np.zeros((B,), np.int32)
-        chunked = False
-        drafts: Dict[int, List[int]] = {}
-        for i in range(B):
-            req = g.slots[i]
-            if req is None:
-                continue
-            pos = int(g.pos_h[i])
-            if req.table is not None and self.ecfg.paged:
-                self.pool.touch_table(req.table)
-                n, need = self._next_chunk_need(req, pos)
-                d = self._draft_for(req, pos) if self._spec else []
-                if d:
-                    # a drafted decode stream writes 1 + k positions this
-                    # tick: growth and CoW must cover the full draft width
-                    # BEFORE the optimistic verify forward touches pages
-                    n = 1 + len(d)
-                    need = (self.pool.pages_needed(pos + n)
-                            - len(req.table.blocks))
-                forks = (self.pool.fork_pages(req.table, pos, n)
-                         if self._share else [])
-                grown = not (self._lazy and self.pool.pages_per_stream
-                             and (need > 0 or forks)) \
-                    or self._grow_stream(req, g, max(need, 0), tuple(forks))
-                if not grown and d:
-                    # speculation is opportunistic: under memory pressure
-                    # drop the draft and retry as a plain decode, so spec
-                    # never parks a stream the non-speculative engine
-                    # would have run this tick
-                    d = []
+        with span("arcas.assemble"):
+            B = self.ecfg.max_batch
+            n_h = np.zeros((B,), np.int32)
+            chunked = False
+            drafts: Dict[int, List[int]] = {}
+            for i in range(B):
+                req = g.slots[i]
+                if req is None:
+                    continue
+                pos = int(g.pos_h[i])
+                if req.table is not None and self.ecfg.paged:
+                    self.pool.touch_table(req.table)
                     n, need = self._next_chunk_need(req, pos)
+                    d = self._draft_for(req, pos) if self._spec else []
+                    if d:
+                        # a drafted decode stream writes 1 + k positions this
+                        # tick: growth and CoW must cover the full draft width
+                        # BEFORE the optimistic verify forward touches pages
+                        n = 1 + len(d)
+                        need = (self.pool.pages_needed(pos + n)
+                                - len(req.table.blocks))
                     forks = (self.pool.fork_pages(req.table, pos, n)
                              if self._share else [])
-                    grown = not (need > 0 or forks) or self._grow_stream(
-                        req, g, max(need, 0), tuple(forks))
-                if not grown:
-                    self._park_stream(g, i)
+                    grown = not (self._lazy and self.pool.pages_per_stream
+                                 and (need > 0 or forks)) \
+                        or self._grow_stream(req, g, max(need, 0),
+                                             tuple(forks))
+                    if not grown and d:
+                        # speculation is opportunistic: under memory pressure
+                        # drop the draft and retry as a plain decode, so spec
+                        # never parks a stream the non-speculative engine
+                        # would have run this tick
+                        d = []
+                        n, need = self._next_chunk_need(req, pos)
+                        forks = (self.pool.fork_pages(req.table, pos, n)
+                                 if self._share else [])
+                        grown = not (need > 0 or forks) or self._grow_stream(
+                            req, g, max(need, 0), tuple(forks))
+                    if not grown:
+                        self._park_stream(g, i)
+                        continue
+                    if self._share:
+                        # writing into a published page forks the page's index
+                        # entry off it (the OLD block keeps its entry)
+                        self.pool.note_writes(req.table, pos, n)
+                    if d:
+                        drafts[i] = d
+                else:
+                    S = len(req.prompt)
+                    n = min(self._chunk, S - pos) if pos < S else 1
+                n_h[i] = n
+                # drafted rows run their OWN verify half; "chunked" tracks
+                # only real prefill chunks so the spec-off paths (and their
+                # counters) stay byte-for-byte unchanged
+                chunked = chunked or (n > 1 and i not in drafts)
+            if not n_h.any():
+                return
+            if self.ecfg.paged and self.pool.inflight_tables():
+                # the overlap clock: a real model tick ran with at least one
+                # D2H transfer on the wire — decode time the spill hid behind
+                self.counters.add("kv_ticks_while_inflight", 1)
+            if self.ecfg.paged:
+                tables, slots1 = self._group_indices(g)
+            pos_j = jnp.asarray(g.pos_h)
+            # per-stream token feed: the next prompt slice for streams still in
+            # prefill (a final chunk may hold a single token), the last emitted
+            # token — plus its draft continuation — for decode streams
+            C = self._chunk if chunked else (self._spec_w if drafts else 1)
+            toks = np.zeros((B, C), np.int32)
+            for i in range(B):
+                req = g.slots[i]
+                if req is None or not n_h[i]:
                     continue
-                if self._share:
-                    # writing into a published page forks the page's index
-                    # entry off it (the OLD block keeps its entry)
-                    self.pool.note_writes(req.table, pos, n)
-                if d:
-                    drafts[i] = d
-            else:
-                S = len(req.prompt)
-                n = min(self._chunk, S - pos) if pos < S else 1
-            n_h[i] = n
-            # drafted rows run their OWN verify half; "chunked" tracks
-            # only real prefill chunks so the spec-off paths (and their
-            # counters) stay byte-for-byte unchanged
-            chunked = chunked or (n > 1 and i not in drafts)
-        if not n_h.any():
-            return
-        if self.ecfg.paged and self.pool.inflight_tables():
-            # the overlap clock: a real model tick ran with at least one
-            # D2H transfer on the wire — decode time the spill hid behind
-            self.counters.add("kv_ticks_while_inflight", 1)
-        if self.ecfg.paged:
-            tables, slots1 = self._group_indices(g)
-        pos_j = jnp.asarray(g.pos_h)
-        # per-stream token feed: the next prompt slice for streams still in
-        # prefill (a final chunk may hold a single token), the last emitted
-        # token — plus its draft continuation — for decode streams
-        C = self._chunk if chunked else (self._spec_w if drafts else 1)
-        toks = np.zeros((B, C), np.int32)
-        for i in range(B):
-            req = g.slots[i]
-            if req is None or not n_h[i]:
-                continue
-            pos = int(g.pos_h[i])
-            if pos < len(req.prompt):
-                toks[i, :n_h[i]] = req.prompt[pos:pos + n_h[i]]
-            else:
-                toks[i, 0] = g.tok_h[i]
-                d = drafts.get(i)
-                if d:
-                    toks[i, 1:1 + len(d)] = d
-        # drafted rows are carved out of the regular paths (n_eff = 0:
-        # gathered but never computed or written) — they run through the
-        # dedicated verify half below, so prefill chunks and plain decode
-        # rows execute the EXACT compiled programs the spec-off engine runs
-        n_eff = n_h.copy()
-        for i in drafts:
-            n_eff[i] = 0
-        deco_rows = [i for i in range(B) if n_eff[i] == 1]
+                pos = int(g.pos_h[i])
+                if pos < len(req.prompt):
+                    toks[i, :n_h[i]] = req.prompt[pos:pos + n_h[i]]
+                else:
+                    toks[i, 0] = g.tok_h[i]
+                    d = drafts.get(i)
+                    if d:
+                        toks[i, 1:1 + len(d)] = d
+            # drafted rows are carved out of the regular paths (n_eff = 0:
+            # gathered but never computed or written) — they run through the
+            # dedicated verify half below, so prefill chunks and plain decode
+            # rows execute the EXACT compiled programs the spec-off engine runs
+            n_eff = n_h.copy()
+            for i in drafts:
+                n_eff[i] = 0
+            deco_rows = [i for i in range(B) if n_eff[i] == 1]
         if chunked:
             # model-step accounting, STRUCTURAL (by construction of the
             # compiled path, not measured at runtime): the fused path is
@@ -1678,15 +1707,17 @@ class ServeEngine:
                     # their query rows are pure masked-FLOP waste
                     self.counters.add("decode_masked_query_rows",
                                       (C - 1) * len(deco_rows))
-                logits, self.pool.storage = self._paged_chunk(
-                    self.params, self.pool.storage, tables, slots1,
-                    jnp.asarray(toks), pos_j, jnp.asarray(n_eff))
-                nxt = np.asarray(dec.next_token_ids(logits,
-                                                    jnp.asarray(n_eff)))
+                with span("arcas.dispatch", step="chunk"):
+                    logits, self.pool.storage = self._paged_chunk(
+                        self.params, self.pool.storage, tables, slots1,
+                        jnp.asarray(toks), pos_j, jnp.asarray(n_eff))
+                with span("arcas.sync"):
+                    nxt = np.asarray(dec.next_token_ids(logits,
+                                                        jnp.asarray(n_eff)))
         elif deco_rows:
-            tokens = jnp.asarray(toks[:, :1])
-            if self.ecfg.paged:
-                if drafts:
+            with span("arcas.assemble"):
+                tokens = jnp.asarray(toks[:, :1])
+                if self.ecfg.paged and drafts:
                     # the single-token step has NO per-row length mask, so
                     # a drafted row riding it would write its ring page
                     # AND advance its recurrent state a second time before
@@ -1703,15 +1734,19 @@ class ServeEngine:
                     tables = jnp.asarray(
                         np.asarray(rowlist, np.int32).reshape(B, P))
                     slots1 = jnp.asarray(np.asarray(slotlist, np.int32))
-                logits, self.pool.storage = self._paged_decode(
-                    self.params, self.pool.storage, tables, slots1,
-                    tokens, pos_j)
-            else:
-                logits, g.cache = self._decode(self.params, g.cache, tokens,
-                                               pos_j)
+            with span("arcas.dispatch", step="decode"):
+                if self.ecfg.paged:
+                    logits, self.pool.storage = self._paged_decode(
+                        self.params, self.pool.storage, tables, slots1,
+                        tokens, pos_j)
+                else:
+                    logits, g.cache = self._decode(self.params, g.cache,
+                                                   tokens, pos_j)
             # idle-slot hardening: slots with n == 0 get the -1 sentinel,
             # never an argmax over a constant (all-zero / all-NEG_INF) row
-            nxt = np.asarray(dec.next_token_ids(logits, jnp.asarray(n_eff)))
+            with span("arcas.sync"):
+                nxt = np.asarray(dec.next_token_ids(logits,
+                                                    jnp.asarray(n_eff)))
         else:
             nxt = np.full((B,), -1, np.int32)   # pure-spec tick
         if deco_rows:
@@ -1757,30 +1792,31 @@ class ServeEngine:
                              self.pool.checkpoint_rows(snap_rows))) \
                 if snap_rows else {}
             spec_lg = self._spec_verify(g, toks, n_h, drafts)
-            reapply: List[Tuple[int, int]] = []
-            rolled: List[dict] = []
-            for i in sorted(drafts):
-                n = int(n_h[i])
-                am = np.argmax(spec_lg[i], axis=-1)
-                # accept the longest prefix where each draft token matches
-                # the verified argmax one position earlier; the token at
-                # the accept boundary comes free (full accept: k+1 tokens)
-                m = 0
-                while m < n - 1 and int(toks[i, m + 1]) == int(am[m]):
-                    m += 1
-                commits[i] = [int(x) for x in am[:m + 1]]
-                self.counters.add("spec_tokens_drafted", n - 1)
-                self.counters.add("spec_tokens_accepted", m)
-                if m + 1 < n:
-                    self.counters.add("spec_rollbacks", 1)
-                    if m == 0:
-                        self.counters.add("spec_full_rejects", 1)
-                    if i in snaps:
-                        rolled.append(snaps[i])
-                        reapply.append((i, m + 1))
-            if rolled:
-                # one batched scatter restores every rejected row
-                self.pool.rollback_rows(rolled)
+            with span("arcas.commit"):
+                reapply: List[Tuple[int, int]] = []
+                rolled: List[dict] = []
+                for i in sorted(drafts):
+                    n = int(n_h[i])
+                    am = np.argmax(spec_lg[i], axis=-1)
+                    # accept the longest prefix where each draft token matches
+                    # the verified argmax one position earlier; the token at
+                    # the accept boundary comes free (full accept: k+1 tokens)
+                    m = 0
+                    while m < n - 1 and int(toks[i, m + 1]) == int(am[m]):
+                        m += 1
+                    commits[i] = [int(x) for x in am[:m + 1]]
+                    self.counters.add("spec_tokens_drafted", n - 1)
+                    self.counters.add("spec_tokens_accepted", m)
+                    if m + 1 < n:
+                        self.counters.add("spec_rollbacks", 1)
+                        if m == 0:
+                            self.counters.add("spec_full_rejects", 1)
+                        if i in snaps:
+                            rolled.append(snaps[i])
+                            reapply.append((i, m + 1))
+                if rolled:
+                    # one batched scatter restores every rejected row
+                    self.pool.rollback_rows(rolled)
             if reapply:
                 self._spec_reapply(g, toks, reapply)
             drafted = self.counters.totals.get("spec_tokens_drafted", 0.0)
@@ -1789,72 +1825,73 @@ class ServeEngine:
                     "spec_accept_rate",
                     self.counters.totals.get("spec_tokens_accepted", 0.0)
                     / drafted)
-        g.steps += 1
-        now = self._clock()
-        for i in range(B):
-            req = g.slots[i]
-            if req is None or not n_h[i]:
-                continue
-            S = len(req.prompt)
-            pos0 = int(g.pos_h[i])
-            if i in commits:
-                # a drafted decode row commits its verified tokens: the
-                # accepted draft prefix plus the free boundary token.  The
-                # cursor lands on the last ACCEPTED position — a park or
-                # spill next tick saves exactly this state
-                out = commits[i]
-                g.pos_h[i] = pos0 + len(out)
-                self.counters.add("tokens_processed", len(out))
-                self.counters.add("decode_committed_tokens", len(out))
-                for tok in out:
-                    assert tok >= 0, f"spec slot {i} emitted a sentinel"
-                    req.generated.append(tok)
-                g.tok_h[i] = out[-1]
-                req.table.used_pages = min(
-                    len(req.table.blocks),
-                    self.pool.pages_needed(pos0 + len(out)))
+        with span("arcas.commit"):
+            g.steps += 1
+            now = self._clock()
+            for i in range(B):
+                req = g.slots[i]
+                if req is None or not n_h[i]:
+                    continue
+                S = len(req.prompt)
+                pos0 = int(g.pos_h[i])
+                if i in commits:
+                    # a drafted decode row commits its verified tokens: the
+                    # accepted draft prefix plus the free boundary token.  The
+                    # cursor lands on the last ACCEPTED position — a park or
+                    # spill next tick saves exactly this state
+                    out = commits[i]
+                    g.pos_h[i] = pos0 + len(out)
+                    self.counters.add("tokens_processed", len(out))
+                    self.counters.add("decode_committed_tokens", len(out))
+                    for tok in out:
+                        assert tok >= 0, f"spec slot {i} emitted a sentinel"
+                        req.generated.append(tok)
+                    g.tok_h[i] = out[-1]
+                    req.table.used_pages = min(
+                        len(req.table.blocks),
+                        self.pool.pages_needed(pos0 + len(out)))
+                    if len(req.generated) >= req.max_new:
+                        req.t_done = now
+                        g.slots[i] = None
+                        self._inflight -= 1
+                        self.pool.free(req.table)  # wakes parked streams
+                    continue
+                new_pos = pos0 + int(n_h[i])
+                g.pos_h[i] = new_pos
+                self.counters.add("tokens_processed", int(n_h[i]))
+                if pos0 >= S:
+                    self.counters.add("decode_committed_tokens", 1)
+                if pos0 < S:
+                    self.counters.add("prefill_chunks", 1)
+                    if self.ecfg.paged:
+                        req.table.used_pages = min(
+                            len(req.table.blocks),
+                            self.pool.pages_needed(new_pos))
+                    if self._share and req.page_keys:
+                        # publish the prompt pages this chunk completed so
+                        # later requests with the same prefix can attach
+                        self.pool.register_prefix(req.table, req.page_keys,
+                                                  pos0, new_pos, S)
+                    if new_pos < S:
+                        continue            # mid-prompt: no token emitted yet
+                    req.t_first = now
+                    self.counters.add("prefills", 1)
+                tok = int(nxt[i])
+                assert tok >= 0, f"idle slot {i} emitted a token"
+                req.generated.append(tok)
+                g.tok_h[i] = tok
+                if self.ecfg.paged:
+                    req.table.used_pages = min(len(req.table.blocks),
+                                               self.pool.pages_needed(new_pos))
                 if len(req.generated) >= req.max_new:
                     req.t_done = now
                     g.slots[i] = None
                     self._inflight -= 1
-                    self.pool.free(req.table)  # wakes parked streams
-                continue
-            new_pos = pos0 + int(n_h[i])
-            g.pos_h[i] = new_pos
-            self.counters.add("tokens_processed", int(n_h[i]))
-            if pos0 >= S:
-                self.counters.add("decode_committed_tokens", 1)
-            if pos0 < S:
-                self.counters.add("prefill_chunks", 1)
-                if self.ecfg.paged:
-                    req.table.used_pages = min(
-                        len(req.table.blocks),
-                        self.pool.pages_needed(new_pos))
-                if self._share and req.page_keys:
-                    # publish the prompt pages this chunk completed so
-                    # later requests with the same prefix can attach
-                    self.pool.register_prefix(req.table, req.page_keys,
-                                              pos0, new_pos, S)
-                if new_pos < S:
-                    continue            # mid-prompt: no token emitted yet
-                req.t_first = now
-                self.counters.add("prefills", 1)
-            tok = int(nxt[i])
-            assert tok >= 0, f"idle slot {i} emitted a token"
-            req.generated.append(tok)
-            g.tok_h[i] = tok
-            if self.ecfg.paged:
-                req.table.used_pages = min(len(req.table.blocks),
-                                           self.pool.pages_needed(new_pos))
-            if len(req.generated) >= req.max_new:
-                req.t_done = now
-                g.slots[i] = None
-                self._inflight -= 1
-                if self.ecfg.paged:
-                    self.pool.free(req.table)  # wakes parked streams
-        self.counters.add("decode_steps", 1)
-        self.counters.add("decode_tokens",
-                          sum(1 for s in g.slots if s is not None))
+                    if self.ecfg.paged:
+                        self.pool.free(req.table)  # wakes parked streams
+            self.counters.add("decode_steps", 1)
+            self.counters.add("decode_tokens",
+                              sum(1 for s in g.slots if s is not None))
 
     # -- engine task (coroutine per group, scheduled by the task runtime) ----
     def _group_task(self, g: _Group):

@@ -77,7 +77,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core.costmodel import kv_cache_bytes, kv_dedup_bytes, \
     kv_spill_bytes
-from repro.core.counters import PerfCounters
+from repro.core.counters import PerfCounters, span
 from repro.launch.steps import make_prefix_fork, make_rows_gather, \
     make_rows_scatter, make_spill_gather, make_spill_gather_async, \
     make_spill_scatter
@@ -537,21 +537,23 @@ class KVBlockPool:
         as necessary as the pages)."""
         if not keys or not self.pages_per_stream:
             return [], 0
-        limit = min(len(keys), (max(prompt_len, 1) - 1) // self.block_tokens,
-                    self.pages_per_stream)
-        blocks: List[int] = []
-        best, ckpt = 0, 0
-        for o in range(limit):
-            e = self._prefix.get(keys[o])
-            if e is None or e.domain != domain:
-                break
-            blocks.append(e.block)
-            if not self.has_state:
-                best = o + 1
-            elif e.state_ckpt:
-                best, ckpt = o + 1, e.state_ckpt
-        for b in blocks[:best]:
-            self._touch_block(b)
+        with span("arcas.pool.match"):
+            limit = min(len(keys),
+                        (max(prompt_len, 1) - 1) // self.block_tokens,
+                        self.pages_per_stream)
+            blocks: List[int] = []
+            best, ckpt = 0, 0
+            for o in range(limit):
+                e = self._prefix.get(keys[o])
+                if e is None or e.domain != domain:
+                    break
+                blocks.append(e.block)
+                if not self.has_state:
+                    best = o + 1
+                elif e.state_ckpt:
+                    best, ckpt = o + 1, e.state_ckpt
+            for b in blocks[:best]:
+                self._touch_block(b)
         return blocks[:best], ckpt
 
     def register_prefix(self, table: KVTable, keys: Sequence[bytes],
@@ -568,29 +570,30 @@ class KVBlockPool:
         opportunistic — checkpoints never compete with admissions)."""
         if not self.pages_per_stream or not keys:
             return
-        bt, W = self.block_tokens, self.spec.width
-        for o in range(max(pos0 // bt, 0),
-                       min(new_pos, prompt_len) // bt):
-            if o >= min(len(keys), self.pages_per_stream,
-                        len(table.blocks)):
-                break
-            if new_pos > o * bt + W:
-                continue        # wrapped inside this very tick: dead page
-            key = keys[o]
-            b = table.blocks[o]
-            if key in self._prefix or b in self._entry_of_block:
-                continue        # already published (or block backs a key)
-            ckpt = 0
-            if self.has_state and new_pos == (o + 1) * bt \
-                    and self._free_states[table.domain]:
-                ckpt = self._free_states[table.domain].pop()
-                self.storage = self._prefix_fork(
-                    self.storage, [], [],
-                    src_state=table.state_slot, dst_state=ckpt)
-            self._prefix[key] = PrefixEntry(b, table.domain, ckpt)
-            self._entry_of_block[b] = key
-            self._touch_block(b)
-            self.counters.add("kv_prefix_pages_published", 1)
+        with span("arcas.pool.publish"):
+            bt, W = self.block_tokens, self.spec.width
+            for o in range(max(pos0 // bt, 0),
+                           min(new_pos, prompt_len) // bt):
+                if o >= min(len(keys), self.pages_per_stream,
+                            len(table.blocks)):
+                    break
+                if new_pos > o * bt + W:
+                    continue        # wrapped inside this very tick: dead page
+                key = keys[o]
+                b = table.blocks[o]
+                if key in self._prefix or b in self._entry_of_block:
+                    continue        # already published (or block backs a key)
+                ckpt = 0
+                if self.has_state and new_pos == (o + 1) * bt \
+                        and self._free_states[table.domain]:
+                    ckpt = self._free_states[table.domain].pop()
+                    self.storage = self._prefix_fork(
+                        self.storage, [], [],
+                        src_state=table.state_slot, dst_state=ckpt)
+                self._prefix[key] = PrefixEntry(b, table.domain, ckpt)
+                self._entry_of_block[b] = key
+                self._touch_block(b)
+                self.counters.add("kv_prefix_pages_published", 1)
 
     def _write_pages(self, pos: int, n: int, n_blocks: int) -> List[int]:
         """Ring-page indices the next ``n``-token write at ``pos``
@@ -624,13 +627,14 @@ class KVBlockPool:
         if not self._free_blocks[table.domain]:
             self.counters.add("kv_grow_failures", 1)
             return False
-        new = self._pop_block(table.domain)
-        self.storage = self._prefix_fork(self.storage, [old], [new])
-        self._release_block(old)    # other holders keep the original
-        table.blocks[page] = new
-        self.counters.add("kv_blocks_allocated", 1)
-        self.counters.add("kv_cow_forks", 1)
-        self._note_usage(table.domain)
+        with span("arcas.pool.cow"):
+            new = self._pop_block(table.domain)
+            self.storage = self._prefix_fork(self.storage, [old], [new])
+            self._release_block(old)    # other holders keep the original
+            table.blocks[page] = new
+            self.counters.add("kv_blocks_allocated", 1)
+            self.counters.add("kv_cow_forks", 1)
+            self._note_usage(table.domain)
         return True
 
     def note_writes(self, table: KVTable, pos: int, n: int):

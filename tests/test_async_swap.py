@@ -21,6 +21,8 @@ overlap safe:
     a failed sweep leg has ZERO side effects (the PR-10 regression: the
     old sweep could leak a state checkpoint on a failed grow).
 """
+import collections
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -170,6 +172,25 @@ def test_async_oversubscription_overlap_counters():
     # by construction, not stalls
     assert kv_s["fence_waits"] == 0
     assert kv_s["ticks_while_inflight"] == 0
+
+
+def test_inflight_spill_reaches_the_step_samples():
+    """The per-round profiler feed carries the transfer engine's gauges:
+    a round sampled while a spill is on the wire shows its pages and
+    bytes in the scheduler's ``StepSample`` stream."""
+    rng = np.random.default_rng(0)
+    sched = [(int(rng.integers(0, 2)),
+              rng.integers(2, CFG.vocab, size=4), 26) for _ in range(6)]
+    eng = _engine(groups=1, max_batch=4, pool_streams=1, async_swap=True)
+    eng.counters.samples = collections.deque(maxlen=100000)
+    eng.open_loop_client(list(sched))
+    res = _drain(eng)
+    assert res["counters"].get("kv_spill_issues", 0) >= 1
+    samples = eng.counters.samples
+    assert max(s.kv_spill_inflight_pages for s in samples) > 0
+    assert max(s.kv_spill_inflight_bytes for s in samples) > 0
+    assert sum(s.kv_ticks_while_inflight for s in samples) == \
+        res["counters"].get("kv_ticks_while_inflight", 0)
 
 
 # ---------------------------------------------------------------------------

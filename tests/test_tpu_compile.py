@@ -63,12 +63,17 @@ B, C, HQ, HKV, DH = 8, 16, 24, 8, 128
 def test_ring_chunk_kernel_compiles_for_v5e(one_chip, W):
     sd = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
     bf = jnp.bfloat16
-    _compile(
+    compiled = _compile(
         lambda q, kn, vn, kc, vc, p, n: ring_chunk_attention(
             q, kn, vn, kc, vc, p, n, interpret=False),
         sd((B, C, HQ, DH), bf), sd((B, C, HKV, DH), bf),
         sd((B, C, HKV, DH), bf), sd((B, W, HKV, DH), bf),
         sd((B, W, HKV, DH), bf), sd((B,), jnp.int32), sd((B,), jnp.int32))
+    # the kernel carries its name into the program (and so into a device
+    # trace), and stays the tpu_custom_call that ring_kernel_roofline reads
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls and all("%ring_chunk_attention" in ln for ln in calls)
 
 
 def test_ring_chunk_kernel_compiles_over_four_chips(topo):
