@@ -537,25 +537,22 @@ def chunk_decode_step(params, cfg: ModelConfig, spec: CacheViewSpec, cache,
 # ---------------------------------------------------------------------------
 
 def _decode_attn_layer(x, lp, lc, cfg: ModelConfig, rope1, pos, *, window):
-    xin = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
-    q = jnp.einsum("bsd,dhk->bshk", xin, lp["attn"]["wq"],
-                   preferred_element_type=jnp.float32).astype(x.dtype)
-    k = jnp.einsum("bsd,dhk->bshk", xin, lp["attn"]["wk"],
-                   preferred_element_type=jnp.float32).astype(x.dtype)
-    v = jnp.einsum("bsd,dhk->bshk", xin, lp["attn"]["wv"],
-                   preferred_element_type=jnp.float32).astype(x.dtype)
-    if rope1 is not None:
-        cos, sin = rope1
-        q = L.apply_rope(q, cos, sin)
-        k = L.apply_rope(k, cos, sin)
+    q, k, v = _attn_proj(L.rms_norm(x, lp["ln1"], cfg.norm_eps), lp["attn"],
+                         rope1, cfg=cfg)
     kc, vc = L.cache_update(lc["k"], lc["v"], k, v, pos)
-    W = kc.shape[1]
-    kv_pos = L.cache_positions(pos, W)
+    return _decode_attn_rest(x, lp, cfg, q, kc, vc, pos, window=window), \
+        {"k": kc, "v": vc}
+
+
+def _decode_attn_rest(x, lp, cfg: ModelConfig, q, kc, vc, pos, *, window):
+    """The new token's attention over its post-write ring (B, W, Hkv, dh),
+    the output projection and the FFN."""
+    kv_pos = L.cache_positions(pos, kc.shape[1])
     o = L.decode_attention(q, kc, vc, kv_pos, pos, window=window)
     h = x + _attn_out(o, lp["attn"], x.dtype)
     f, _ = _ffn(L.rms_norm(h, lp["ln2"], cfg.norm_eps), lp, cfg,
                 dropless=True)
-    return h + f, {"k": kc, "v": vc}
+    return h + f
 
 
 def _decode_layer(x, lp, lc, cfg: ModelConfig, lt: str, rope1, pos, *,
@@ -584,17 +581,8 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos, extras=None,
     Returns (logits (B, V) f32, new cache).
     """
     from repro.models.transformer import _wsc_tree
-    extras = extras or {}
     x = embed_tokens(params, cfg, tokens)
-    if cfg.rope_type == "mrope":
-        pid = extras.get("position_ids",
-                         jnp.broadcast_to(pos[None, :, None], (3,) + tokens.shape))
-        rope1 = L.mrope_tables(pid, cfg.head_dim, cfg.rope_theta,
-                               cfg.mrope_sections)
-    elif cfg.rope_type == "none":
-        rope1 = None
-    else:
-        rope1 = L.rope_tables(pos[:, None], cfg.head_dim, cfg.rope_theta)
+    rope1 = _decode_rope(cfg, tokens, pos, extras)
 
     if cfg.family == "encdec":
         def body(x, inp):
@@ -602,16 +590,7 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos, extras=None,
             lp = _wsc_tree(lp, gather_specs and gather_specs.get("dec_layers"))
             # 1. self-attention (ln1) with ring cache
             xin = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
-            q = jnp.einsum("bsd,dhk->bshk", xin, lp["attn"]["wq"],
-                           preferred_element_type=jnp.float32).astype(x.dtype)
-            k = jnp.einsum("bsd,dhk->bshk", xin, lp["attn"]["wk"],
-                           preferred_element_type=jnp.float32).astype(x.dtype)
-            v = jnp.einsum("bsd,dhk->bshk", xin, lp["attn"]["wv"],
-                           preferred_element_type=jnp.float32).astype(x.dtype)
-            if rope1 is not None:
-                cos, sin = rope1
-                q = L.apply_rope(q, cos, sin)
-                k = L.apply_rope(k, cos, sin)
+            q, k, v = _attn_proj(xin, lp["attn"], rope1, cfg=cfg)
             kc, vc = L.cache_update(lc["self_c"]["k"], lc["self_c"]["v"],
                                     k, v, pos)
             W = kc.shape[1]
@@ -672,9 +651,107 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos, extras=None,
         x, new_layers = lax.scan(body, x, (params["layers"], cache["layers"]))
         new_cache = {"layers": new_layers}
 
+    return _decode_head(params, cfg, x), new_cache
+
+
+def _decode_rope(cfg: ModelConfig, tokens, pos, extras=None):
+    """RoPE tables of one token per stream at ``pos`` (None without RoPE);
+    M-RoPE reads ``extras["position_ids"]`` when given."""
+    if cfg.rope_type == "mrope":
+        pid = (extras or {}).get(
+            "position_ids",
+            jnp.broadcast_to(pos[None, :, None], (3,) + tokens.shape))
+        return L.mrope_tables(pid, cfg.head_dim, cfg.rope_theta,
+                              cfg.mrope_sections)
+    if cfg.rope_type == "none":
+        return None
+    return L.rope_tables(pos[:, None], cfg.head_dim, cfg.rope_theta)
+
+
+def _decode_head(params, cfg: ModelConfig, x):
+    """(B, 1, D) last hidden state -> (B, V) f32 logits."""
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = head_logits(params, cfg, x[:, 0])
-    return logits, new_cache
+    return head_logits(params, cfg, x[:, 0])
+
+
+# ---------------------------------------------------------------------------
+# Paged single-token decode (the serving engine's decode program)
+# ---------------------------------------------------------------------------
+
+def decode_writes_in_place(cfg: ModelConfig, spec: CacheViewSpec) -> bool:
+    """Whether the paged decode step can read the pool's pages per layer
+    and write back only the new token: the serving cache is the plain
+    ``layers`` stack of attention rings (dense, MoE and VLM families).
+    Hybrid and SSM models carry per-stream state leaves and enc-dec models
+    cross-attention caches; they gather, step and scatter whole views."""
+    return (cfg.family != "encdec" and not cfg.block_pattern
+            and all(s.token_axis is not None for s in spec.leaves))
+
+
+def paged_decode_step(params, cfg: ModelConfig, pool, tables, tokens, pos):
+    """``decode_step`` read from and written to a block pool in place.
+
+    pool: the ``init_block_pool`` storage of a ``decode_writes_in_place``
+    model, leaves (L, n_blocks, bt, Hkv, dh); tables: (B, P) block ids in
+    ring order, so the ring width is W = P * bt; tokens (B, 1); pos (B,).
+
+    Each layer gathers its own pages of the batch's tables into a
+    (B, W, Hkv, dh) ring and selects the new token in at slot pos % W: the
+    ring ``L.cache_update`` writes, so attention reads exactly the values
+    ``decode_step`` over a gathered view reads and the logits are the
+    same.  The layer scan carries only the hidden state and returns the
+    new token's K/V, (L, B, Hkv, dh); one scatter after it writes them to
+    ``tables[b, (pos % W) // bt]`` at offset ``pos % bt``.  That page is
+    the stream's own: the engine forks a shared page before a step writes
+    into it.  Rows on the null table write into block 0, which is never
+    read.  Returns (logits (B, V) f32, new pool)."""
+    kp, vp = pool["layers"]["k"], pool["layers"]["v"]
+    B, P = tables.shape
+    bt = kp.shape[2]
+    W = P * bt
+    slot = pos % W
+    new_slot = (jnp.arange(W)[None, :] == slot[:, None])[:, :, None, None]
+
+    def ring(leaf, layer, new):
+        pages = leaf[layer, tables]                     # (B, P, bt, Hkv, dh)
+        return jnp.where(new_slot, new, pages.reshape((B, W) + leaf.shape[3:]))
+
+    rope1 = _decode_rope(cfg, tokens, pos)
+
+    def body(x, inp):
+        lp, layer = inp
+        q, k, v = _attn_proj(L.rms_norm(x, lp["ln1"], cfg.norm_eps),
+                             lp["attn"], rope1, cfg=cfg)
+        x = _decode_attn_rest(x, lp, cfg, q, ring(kp, layer, k),
+                              ring(vp, layer, v), pos, window=cfg.window)
+        return x, (k[:, 0], v[:, 0])
+
+    x, (k_new, v_new) = lax.scan(
+        body, embed_tokens(params, cfg, tokens),
+        (params["layers"], jnp.arange(kp.shape[0])))
+    blk = jnp.take_along_axis(tables, (slot // bt)[:, None], axis=1)[:, 0]
+    off = slot % bt
+    pool = {"layers": {"k": kp.at[:, blk, off].set(k_new),
+                       "v": vp.at[:, blk, off].set(v_new)}}
+    return _decode_head(params, cfg, x), pool
+
+
+def make_paged_decode(cfg: ModelConfig, spec: CacheViewSpec):
+    """The engine's decode program, (params, storage, tables, state_slots,
+    tokens, pos) -> (logits, storage): ``paged_decode_step`` where
+    ``decode_writes_in_place`` holds, else gather the batch's views,
+    ``decode_step`` and scatter them back."""
+    if decode_writes_in_place(cfg, spec):
+        def paged_decode(params, storage, tables, state_slots, tokens, pos):
+            return paged_decode_step(params, cfg, storage, tables, tokens,
+                                     pos)
+    else:
+        def paged_decode(params, storage, tables, state_slots, tokens, pos):
+            view = gather_cache_view(storage, spec, tables, state_slots)
+            logits, view = decode_step(params, cfg, view, tokens, pos)
+            return logits, scatter_cache_view(storage, spec, tables,
+                                              state_slots, view)
+    return paged_decode
 
 
 # ---------------------------------------------------------------------------

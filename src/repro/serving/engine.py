@@ -454,8 +454,11 @@ class ServeEngine:
                 self.pool.set_watermarks(*ecfg.spill_watermarks)
             # donate the pool storage: the scatter-back updates in place
             # instead of copying the whole fleet's blocks every tick
-            self._paged_decode = jax.jit(self._make_paged_decode(),
-                                         donate_argnums=(1,))
+            self._paged_decode = jax.jit(
+                dec.make_paged_decode(cfg, self.pool.spec),
+                donate_argnums=(1,))
+            self._decode_in_place = dec.decode_writes_in_place(
+                cfg, self.pool.spec)
             self._commit_prefill = jax.jit(self._make_commit_prefill(),
                                            donate_argnums=(0,))
             ml = ecfg.max_len
@@ -495,6 +498,7 @@ class ServeEngine:
                 self.drafter = None
         else:
             self._kv_fn = None
+            self._decode_in_place = False
             self._chunk = 1
             self._share = False
             self._spec = False
@@ -927,18 +931,6 @@ class ServeEngine:
                 self._spawn_group(g)
 
     # -- paged device-side step builders -------------------------------------
-    def _make_paged_decode(self):
-        cfg, spec = self.cfg, self.pool.spec
-
-        def paged_decode(params, storage, tables, state_slots, tokens, pos):
-            view = dec.gather_cache_view(storage, spec, tables, state_slots)
-            logits, view = dec.decode_step(params, cfg, view, tokens, pos)
-            storage = dec.scatter_cache_view(storage, spec, tables,
-                                             state_slots, view)
-            return logits, storage
-
-        return paged_decode
-
     def _make_paged_chunk(self, mode: str = "scan"):
         """The continuous-batching mixed step: prefill chunks and decode
         streams share one gather -> chunked-masked step -> scatter.
@@ -1755,6 +1747,8 @@ class ServeEngine:
                 if int(g.pos_h[i]) >= len(g.slots[i].prompt)))
             if not chunked or self.ecfg.split_ticks:
                 self.counters.add("decode_forwards", 1)
+                if self._decode_in_place:
+                    self.counters.add("decode_inplace_forwards", 1)
         # -- speculative verify half: one all-logits fused forward over the
         # drafted rows, then greedy acceptance with checkpoint rollback
         commits: Dict[int, List[int]] = {}
@@ -2115,8 +2109,8 @@ class ServeEngine:
                   "spec_row_reapplies", "spec_tokens_drafted",
                   "spec_tokens_accepted", "spec_rollbacks",
                   "spec_full_rejects", "spec_accept_rate",
-                  "decode_forwards", "decode_row_forwards",
-                  "decode_committed_tokens"):
+                  "decode_forwards", "decode_inplace_forwards",
+                  "decode_row_forwards", "decode_committed_tokens"):
             s[k] = tot.get(k, 0.0)
         rejected = s["spec_tokens_drafted"] - s["spec_tokens_accepted"]
         s["spec_rejected_bytes"] = spec_rejected_bytes(self.cfg,

@@ -625,3 +625,34 @@ def test_lazy_admits_more_concurrency_than_eager_same_budget():
         toks[mode] = [r.generated for r in reqs]
     assert toks["lazy"] == toks["eager"]
     assert peaks["lazy"] > peaks["eager"]
+
+
+@pytest.mark.parametrize("arch,in_place", [
+    ("llama3-8b", True),            # ring-only cache: new token written back
+    ("recurrentgemma-9b", False),   # state leaves: gather, step, scatter
+    ("mamba2-780m", False),
+])
+def test_decode_inplace_forwards_counts_the_paged_decode_path(arch, in_place):
+    """Every decode forward of a ring-only model takes the write-back of
+    the new token alone (``decode_inplace_forwards`` == ``decode_forwards``);
+    hybrid and SSM models keep the whole-view path (0).  Either way the
+    paged engine serves the slot monolith's tokens."""
+    cfg = reduced_config(REGISTRY[arch])
+    rng = np.random.default_rng(23)
+    prompts = [rng.integers(2, cfg.vocab, size=s) for s in (5, 27, 12)]
+    max_new = [18, 6, 11]
+    topo = ChipletTopology(n_pods=1, groups_per_pod=1, chips_per_group=1)
+    outs, kv = {}, None
+    for paged in (True, False):
+        ecfg = EngineConfig(max_batch=4, max_len=32, paged=paged,
+                            lazy=paged, pool_streams=4, adaptive=False)
+        eng = ServeEngine(cfg, topo, ecfg, spread_rate=1, seed=0)
+        reqs = [eng.submit(p, max_new=m) for p, m in zip(prompts, max_new)]
+        eng.run_until_done()
+        assert all(r.done for r in reqs)
+        outs[paged] = [r.generated for r in reqs]
+        kv = kv or eng.kv_stats()
+    assert outs[True] == outs[False]
+    assert kv["decode_forwards"] > 0
+    assert kv["decode_inplace_forwards"] == (kv["decode_forwards"]
+                                             if in_place else 0)
