@@ -6,7 +6,9 @@ in a file of its own, found by the name the cell or metric gives:
 
 * ``bench/configs/<config>.json``: the model as it is run, its engine sizing,
   its source and what was cut (``reduced``) or assumed (``assumed``); its
-  ``reference`` names the plain forward in ``bench/references/<name>.py``;
+  ``reference`` names the architecture module ``bench/references/<name>.py``:
+  the weights' layout, the plain forward and the FLOP and byte counts
+  (``bench/spec.py``'s ``reference`` lists what it defines);
 * ``bench/traffic/<mix>.json``: the parameters of one traffic mix, read by
   the one generator in ``bench/traffic.py``;
 * ``bench/metrics/<metric>.py``: one reader per per-layer metric, ``read(rec)``

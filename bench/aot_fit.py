@@ -9,14 +9,19 @@ program's ``memory_analysis()`` beside the weights and the KV pool:
     JAX_PLATFORMS=cpu python3 bench/aot_fit.py starcoder2-15b-l10 [--batch 16]
 
 Nothing is allocated: parameters, pool and inputs are shapes only.  The
-steps are built as the engine builds them (gather the batch's rings from
-the pool, the model step, scatter back).
+steps are the engine's own programs, built by its own builders at its
+default prefill mode and chunk kernel: ``ServeEngine._make_paged_chunk``
+(gather the batch's rings from the pool, the fused chunk forward, scatter
+back) and ``decode.make_paged_decode`` (the decode program the engine
+jits).  The KV bytes a token holds come from the configuration's
+architecture module.
 """
 from __future__ import annotations
 
 import argparse
 import os
 import sys
+import types
 from pathlib import Path
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -29,7 +34,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from bench import costs, serve, spec  # noqa: E402
+from bench import serve, spec  # noqa: E402
 
 GB = 1e9
 
@@ -42,26 +47,17 @@ def _force_compiled_kernels():
         k.resolve_interpret = lambda interpret=None: False
 
 
-def steps(cfg, max_len: int):
-    from repro.launch.steps import make_serve_chunk_step
+def steps(cfg, sp, device):
+    """The engine's (chunk, decode) programs for one device, before jit."""
     from repro.models import decode as dec
-    sp = dec.cache_view_specs(cfg, max_len)
-    chunk_step = make_serve_chunk_step(cfg, sp, mode="parallel",
-                                       chunk_kernel="blocked")
-
-    def paged_chunk(params, storage, tables, slots, tokens, pos, n):
-        view = dec.gather_cache_view(storage, sp, tables, slots)
-        logits, view = chunk_step(params, view, tokens, pos, n)
-        return logits, dec.scatter_cache_view(storage, sp, tables, slots,
-                                               view)
-
-    def paged_decode(params, storage, tables, slots, tokens, pos):
-        view = dec.gather_cache_view(storage, sp, tables, slots)
-        logits, view = dec.decode_step(params, cfg, view, tokens, pos)
-        return logits, dec.scatter_cache_view(storage, sp, tables, slots,
-                                              view)
-
-    return sp, paged_chunk, paged_decode
+    from repro.serving.engine import EngineConfig, ServeEngine
+    e = EngineConfig()
+    # the chunk builder reads only these of its engine
+    host = types.SimpleNamespace(cfg=cfg, devices=[device],
+                                 pool=types.SimpleNamespace(spec=sp),
+                                 _chunk_kernel=e.chunk_kernel)
+    return (ServeEngine._make_paged_chunk(host, e.prefill_mode),
+            dec.make_paged_decode(cfg, sp))
 
 
 def fit(name: str, batch: int = 0, streams: int = 0) -> dict:
@@ -71,6 +67,7 @@ def fit(name: str, batch: int = 0, streams: int = 0) -> dict:
     from repro.serving.kvpool import KVBlockPool
     _force_compiled_kernels()
     conf = spec.config(name)
+    arch = spec.reference(conf["reference"])
     e = conf["engine"]
     B = batch or e["max_batch"]
     streams = streams or e["pool_streams"]
@@ -81,7 +78,8 @@ def fit(name: str, batch: int = 0, streams: int = 0) -> dict:
     sd = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)  # noqa: E731
     params = jax.tree.map(lambda a: sd(a.shape, a.dtype),
                           abstract_params(cfg))
-    sp, paged_chunk, paged_decode = steps(cfg, e["max_len"])
+    sp = dec.cache_view_specs(cfg, e["max_len"])
+    paged_chunk, paged_decode = steps(cfg, sp, topo.devices[0])
     budget = KVBlockPool.blocks_for_streams(cfg, e["max_len"], streams, 16)
     pages = sp.width // 16
     storage = jax.eval_shape(lambda: dec.init_block_pool(
@@ -94,7 +92,7 @@ def fit(name: str, batch: int = 0, streams: int = 0) -> dict:
                             for a in jax.tree.leaves(params)) / GB,
            "pool_gb": sum(a.size * a.dtype.itemsize
                           for a in jax.tree.leaves(storage)) / GB,
-           "kv_token_bytes": costs.kv_token_bytes(conf["model"])}
+           "kv_token_bytes": arch.kv_token_bytes(conf["model"])}
     for kind, fn, args in (
             ("chunk", paged_chunk,
              (params, storage, sd((B, pages), i32), sd((B,), i32),

@@ -1,68 +1,22 @@
-"""Operations and bytes the serving path needs, computed from shapes.
+"""Operations and bytes the serving path needs, computed from shapes:
+the parts that hold for every architecture.
 
 These are the benchmark's own yardstick, taken from the configuration file's
-``model`` block (a dict), never from the program under test.  Dense models
-with grouped-query attention only: ``n_layers`` layers of attention + MLP,
-then the output head.  Weights are bf16 (2 bytes).
-
-Prefill charges the head once per prompt, on its last token: a chunk step
-computes logits for one token per stream and only the last prompt token's
-logits are used.
+``model`` block (a dict) and the run's requests, never from the program
+under test.  What depends on the architecture (a token's FLOPs, the
+weights' and a token's KV bytes) is counted by the module the
+configuration's ``reference`` names (``bench/references/<name>.py``).
+Weights are bf16 (2 bytes).
 """
 from __future__ import annotations
 
-from typing import Dict, Sequence
+from typing import Dict
 
 BF16 = 2
 
 
-def _glu(m: Dict) -> bool:
-    return m["activation"] in ("swiglu", "gelu_glu", "relu_glu")
-
-
-def head_dim(m: Dict) -> int:
-    return m.get("head_dim") or m["d_model"] // m["n_heads"]
-
-
 def vocab_padded(m: Dict) -> int:
     return -(-m["vocab"] // 256) * 256
-
-
-def layer_params(m: Dict) -> int:
-    """Matmul parameters of one layer (norm scales excluded)."""
-    D, F = m["d_model"], m["d_ff"]
-    Hq, Hkv, dh = m["n_heads"], m["n_kv_heads"], head_dim(m)
-    attn = D * (Hq + 2 * Hkv) * dh + Hq * dh * D
-    mlp = (3 if _glu(m) else 2) * D * F
-    return attn + mlp
-
-
-def head_params(m: Dict) -> int:
-    return m["d_model"] * vocab_padded(m)
-
-
-def attn_flops(m: Dict, context: float) -> float:
-    """Score and value FLOPs of one query token against ``context`` keys,
-    over all layers."""
-    return m["n_layers"] * 2 * 2 * m["n_heads"] * head_dim(m) * context
-
-
-def token_flops(m: Dict, context: float, *, head: bool) -> float:
-    """Forward FLOPs of one token whose query sees ``context`` keys: the
-    layer matmuls, attention, and the head only when ``head``."""
-    f = 2.0 * m["n_layers"] * layer_params(m) + attn_flops(m, context)
-    if head:
-        f += 2.0 * head_params(m)
-    return f
-
-
-def prefill_flops(m: Dict, tokens: float, prompts: float,
-                  mean_keys: float) -> float:
-    """FLOPs to prefill ``tokens`` prompt tokens that complete ``prompts``
-    prompts, each query seeing ``mean_keys`` keys on average: the head runs
-    once per prompt, on its last token."""
-    return tokens * token_flops(m, mean_keys, head=False) \
-        + prompts * 2.0 * head_params(m)
 
 
 def prefill_mean_keys(requests) -> float:
@@ -91,28 +45,6 @@ def decode_mean_keys(requests) -> float:
             n += k
             keys += k * (s + 1 + s + k) / 2.0
     return keys / n if n else 0.0
-
-
-def kv_token_bytes(m: Dict) -> int:
-    """K and V bytes one token holds over all layers."""
-    return m["n_layers"] * 2 * m["n_kv_heads"] * head_dim(m) * BF16
-
-
-def weight_bytes(m: Dict) -> int:
-    """Bytes a step reads once: every layer's weights, the norms and the
-    head (the embedding gather is a few rows and is left out)."""
-    D = m["d_model"]
-    norms = (2 * m["n_layers"] + 1) * D
-    return (m["n_layers"] * layer_params(m) + head_params(m) + norms) * BF16
-
-
-def decode_bytes(m: Dict, steps: float, rows: float,
-                 mean_keys: float) -> float:
-    """Bytes ``steps`` decode steps of ``rows`` rows in all need: the
-    weights once a step, each row's live KV (``mean_keys`` keys on
-    average) read, and its new token's K and V written."""
-    return steps * weight_bytes(m) + rows * (mean_keys + 1) \
-        * kv_token_bytes(m)
 
 
 def ring_kernel_cost(bhq: int, cp: int, bhkv: int, lp: int, dh: int, *,

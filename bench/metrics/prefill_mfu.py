@@ -3,12 +3,13 @@ the traced seconds over the chunk-step programs' device time times the
 chip's bf16 peak (%).  The whole chunk step, not one kernel.  Layer matmuls
 and attention count for every prompt token at the mean context of the
 window's prefilled tokens; the head counts once per prompt, on its last
-token.  Moves ttft_p50_ms."""
+token.  Counts from the configuration's architecture module
+(``rec["arch"]``).  Moves ttft_p50_ms."""
 from bench import costs, trace
 
 
 def read(rec):
-    tr, c, m = rec["trace"], rec["counters"], rec["model"]
+    tr, c, m, arch = rec["trace"], rec["counters"], rec["model"], rec["arch"]
     if tr is None or rec["peaks"] is None:
         return None
     sec, _ = trace.module_seconds(tr, "paged_chunk")
@@ -19,5 +20,5 @@ def read(rec):
     keys = costs.prefill_mean_keys(rec["requests"])
     if not keys:
         return None
-    flops = costs.prefill_flops(m, toks, c.get("prefills", 0.0), keys)
+    flops = arch.prefill_flops(m, toks, c.get("prefills", 0.0), keys)
     return 100.0 * flops / (sec * rec["peaks"]["bf16_flops"])

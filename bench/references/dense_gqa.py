@@ -22,6 +22,12 @@ scores and values, the head) and the embedding with both operands rounded
 to fp8 (e4m3, one scale per tensor): the control the benchmark's check must
 refuse.
 
+The module also holds what the harness needs to know of this architecture
+(see ``bench/spec.py``'s ``reference``): ``layout(m)``, the benchmark's
+weights in the engine's parameter tree, and the FLOP and byte counts the
+per-layer readers take (``token_flops``, ``prefill_flops``,
+``weight_bytes``, ``kv_token_bytes``, ``decode_bytes``).
+
 It imports nothing of the program under test.
 """
 from __future__ import annotations
@@ -33,6 +39,8 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import costs
 
 HI = jax.lax.Precision.HIGHEST
 Q_BLOCK = 256            # query rows per attention block
@@ -173,3 +181,122 @@ def scores(params, m: Dict, tokens: np.ndarray, rows: np.ndarray,
         for name, v in zip(("max", "std", "argmax", "score"), res):
             out[name].append(np.asarray(v)[:k])
     return {k: np.concatenate(v) for k, v in out.items()}
+
+
+# -- the weights -------------------------------------------------------------
+
+def layout(m: Dict) -> Dict:
+    """Leaf -> (shape, init) where init is a std or "ones", in the tree
+    layout the engine's parameters take:
+
+        embed (V, D), final_norm (D,), head (D, V),
+        layers: ln1 (L, D), ln2 (L, D),
+                attn: wq (L, D, Hq, dh), wk/wv (L, D, Hkv, dh),
+                      wo (L, Hq, dh, D),
+                mlp:  wi (L, D, F), wo (L, F, D)
+
+    with V the vocabulary rounded up to 256 (the padded head columns are
+    masked by both sides).  Projections are normal with standard deviation
+    fan_in ** -0.5, the two that write into the residual stream
+    (attention's and the MLP's output) scaled by (2 L) ** -0.5; the
+    embedding has standard deviation 1, norm scales are 1.  With a small
+    embedding and unscaled residual writes, ten random layers drive every
+    position to a handful of tokens, and a check of served tokens then sees
+    little."""
+    D, F, L = m["d_model"], m["d_ff"], m["n_layers"]
+    Hq, Hkv, dh = m["n_heads"], m["n_kv_heads"], head_dim(m)
+    V = costs.vocab_padded(m)
+    res = (2 * L) ** -0.5
+    if m["activation"] not in ("gelu", "squared_relu"):
+        raise ValueError(f"dense reference covers gelu and squared_relu "
+                         f"MLPs, not {m['activation']!r}")
+    return {
+        "embed": ((V, D), 1.0),
+        "final_norm": ((D,), "ones"),
+        "head": ((D, V), D ** -0.5),
+        "layers": {
+            "ln1": ((L, D), "ones"),
+            "ln2": ((L, D), "ones"),
+            "attn": {"wq": ((L, D, Hq, dh), D ** -0.5),
+                     "wk": ((L, D, Hkv, dh), D ** -0.5),
+                     "wv": ((L, D, Hkv, dh), D ** -0.5),
+                     "wo": ((L, Hq, dh, D), (Hq * dh) ** -0.5 * res)},
+            "mlp": {"wi": ((L, D, F), D ** -0.5),
+                    "wo": ((L, F, D), F ** -0.5 * res)},
+        },
+    }
+
+
+# -- operations and bytes ----------------------------------------------------
+#
+# ``n_layers`` layers of attention + MLP, then the output head; weights are
+# bf16.  Prefill charges the head once per prompt, on its last token: a
+# chunk step computes logits for one token per stream and only the last
+# prompt token's logits are used.
+
+def _glu(m: Dict) -> bool:
+    return m["activation"] in ("swiglu", "gelu_glu", "relu_glu")
+
+
+def head_dim(m: Dict) -> int:
+    return m.get("head_dim") or m["d_model"] // m["n_heads"]
+
+
+def layer_params(m: Dict) -> int:
+    """Matmul parameters of one layer (norm scales excluded)."""
+    D, F = m["d_model"], m["d_ff"]
+    Hq, Hkv, dh = m["n_heads"], m["n_kv_heads"], head_dim(m)
+    attn = D * (Hq + 2 * Hkv) * dh + Hq * dh * D
+    mlp = (3 if _glu(m) else 2) * D * F
+    return attn + mlp
+
+
+def head_params(m: Dict) -> int:
+    return m["d_model"] * costs.vocab_padded(m)
+
+
+def attn_flops(m: Dict, context: float) -> float:
+    """Score and value FLOPs of one query token against ``context`` keys,
+    over all layers."""
+    return m["n_layers"] * 2 * 2 * m["n_heads"] * head_dim(m) * context
+
+
+def token_flops(m: Dict, context: float, *, head: bool) -> float:
+    """Forward FLOPs of one token whose query sees ``context`` keys: the
+    layer matmuls, attention, and the head only when ``head``."""
+    f = 2.0 * m["n_layers"] * layer_params(m) + attn_flops(m, context)
+    if head:
+        f += 2.0 * head_params(m)
+    return f
+
+
+def prefill_flops(m: Dict, tokens: float, prompts: float,
+                  mean_keys: float) -> float:
+    """FLOPs to prefill ``tokens`` prompt tokens that complete ``prompts``
+    prompts, each query seeing ``mean_keys`` keys on average: the head runs
+    once per prompt, on its last token."""
+    return tokens * token_flops(m, mean_keys, head=False) \
+        + prompts * 2.0 * head_params(m)
+
+
+def kv_token_bytes(m: Dict) -> int:
+    """K and V bytes one token holds over all layers."""
+    return m["n_layers"] * 2 * m["n_kv_heads"] * head_dim(m) * costs.BF16
+
+
+def weight_bytes(m: Dict) -> int:
+    """Bytes a step reads once: every layer's weights, the norms and the
+    head (the embedding gather is a few rows and is left out)."""
+    D = m["d_model"]
+    norms = (2 * m["n_layers"] + 1) * D
+    return (m["n_layers"] * layer_params(m) + head_params(m) + norms) \
+        * costs.BF16
+
+
+def decode_bytes(m: Dict, steps: float, rows: float,
+                 mean_keys: float) -> float:
+    """Bytes ``steps`` decode steps of ``rows`` rows in all need: the
+    weights once a step, each row's live KV (``mean_keys`` keys on
+    average) read, and its new token's K and V written."""
+    return steps * weight_bytes(m) + rows * (mean_keys + 1) \
+        * kv_token_bytes(m)

@@ -128,7 +128,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         f"compile cache {cache}")
     compiles = serve.CompileCounter()
 
-    weights = bmodel.draw(m, seed, dev)
+    arch = c["arch"]
+    weights = bmodel.draw(arch.layout(m), seed, dev)
     jax.block_until_ready(weights)
     cfg, eng = serve.build_engine(conf, weights, devs)
     calls = serve.warm(eng, dev)
@@ -185,7 +186,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
             del loaded
         w.discard_trace()
         rec = {"requests": reqs_out, "trace": tr,
-               "counters": w.counter_deltas(), "model": m,
+               "counters": w.counter_deltas(), "model": m, "arch": arch,
                "engine": conf["engine"], "peaks": pk,
                "trace_s": (w.trace_t[1] - w.trace_t[0])
                if len(w.trace_t) == 2 else None}
@@ -205,12 +206,11 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     finished = [_Served(s.req) for s in w.sent if s.req.done]
     del w, eng
     gc.collect()
-    ref = spec.reference(conf["reference"])
     lim = conf["check"]
     smp = check.sample(finished, seed, lim["sample_tokens"],
                        lim["sample_requests"])
     t0 = time.monotonic()
-    g = check.gaps(ref, weights, m, smp, pad_to=traffic.max_tokens(mix),
+    g = check.gaps(arch, weights, m, smp, pad_to=traffic.max_tokens(mix),
                    control=control)
     gap_max = check.widest(g["served"]) if smp else None
     checks = check.verdict(gap_max, unfinished, lim["gap_max_std"])

@@ -40,7 +40,35 @@ def _load_module(path: Path, modname: str):
 
 
 def reference(name: str, bench: Path = BENCH):
-    """The plain reference module a configuration names."""
+    """The architecture module a configuration's ``reference`` names,
+    ``references/<name>.py``: everything the harness knows of one
+    architecture, so that a new one is added as files only.  ``m`` is the
+    configuration's ``model`` block; counts are of bf16 weights.  It
+    defines:
+
+    * ``layout(m)``: the benchmark's weights as a tree of leaf ->
+      ``(shape, init)``, init a standard deviation or ``"ones"``, in the
+      engine's parameter layout (``bench/model.py`` draws it; the engine
+      checks it against its own tree);
+    * ``scores(params, m, tokens, rows, score, quant=None, pad_to=0)``: the
+      plain reference forward over one sequence, returning at positions
+      ``rows`` the logits' ``max``, ``std``, ``argmax`` and the logits of
+      the token ids ``score``; ``quant="fp8"`` is the control
+      (``bench/check.py``);
+    * ``token_flops(m, context, *, head)``: forward FLOPs of one token whose
+      query sees ``context`` keys, with the output head only when ``head``;
+    * ``prefill_flops(m, tokens, prompts, mean_keys)``: FLOPs to prefill
+      ``tokens`` prompt tokens at ``mean_keys`` keys on average that
+      complete ``prompts`` prompts (the head once per prompt);
+    * ``weight_bytes(m)``: bytes one step reads once (weights, norms,
+      head);
+    * ``kv_token_bytes(m)``: cache bytes one token holds over all layers;
+    * ``decode_bytes(m, steps, rows, mean_keys)``: bytes ``steps`` decode
+      steps of ``rows`` rows in all need, each row reading ``mean_keys``
+      cached tokens on average and writing its own.
+
+    The counts are model FLOPs and least bytes: what the architecture
+    needs, not what one implementation of it does."""
     return _load_module(bench / "references" / f"{name}.py",
                         f"bench_reference_{name.replace('-', '_')}")
 
@@ -59,8 +87,9 @@ def metric_reader(name: str, bench: Path = BENCH
 
 
 def cell(name: str, root: Path = ROOT) -> Dict[str, Any]:
-    """The workload entry named ``name``, with its configuration, mix and
-    the metrics it reports, all resolved from files."""
+    """The workload entry named ``name``, with its configuration, its
+    architecture module (``arch``), mix and the metrics it reports, all
+    resolved from files."""
     bm = benchmark(root)
     found = [w for w in bm["workloads"] if w["name"] == name]
     if not found:
@@ -74,8 +103,9 @@ def cell(name: str, root: Path = ROOT) -> Dict[str, Any]:
     per_layer = [m for m in bm["per_layer"]
                  if name in m.get("workloads", [name])
                  and m["moves"] in reported]
-    return {"workload": w, "chips": w["chips"],
-            "config": config(w["config"], bench),
+    conf = config(w["config"], bench)
+    return {"workload": w, "chips": w["chips"], "config": conf,
+            "arch": reference(conf["reference"], bench),
             "mix": mix(w["traffic"], bench),
             "end_to_end": e2e, "per_layer": per_layer}
 

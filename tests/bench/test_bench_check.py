@@ -37,6 +37,7 @@ def tiny_cell():
     harness computes."""
     c = {"workload": {"name": "tiny"}, "chips": 1,
          "config": copy.deepcopy(spec.config("starcoder2-15b-l10")),
+         "arch": spec.reference("dense_gqa"),
          "mix": copy.deepcopy(spec.mix("code-prefix")),
          "end_to_end": [{"name": k, "unit": u} for k, u in E2E.items()],
          "per_layer": []}

@@ -97,10 +97,12 @@ def test_readers_on_a_synthetic_record():
     """Per-layer readers on the synthetic trace with hand-set counters and
     a model whose costs are easy to count."""
     from bench import costs, spec
+    dense = spec.reference("dense_gqa")
     m = {"n_layers": 1, "d_model": 2, "n_heads": 2, "n_kv_heads": 1,
          "head_dim": 1, "d_ff": 4, "vocab": 256, "activation": "gelu"}
     pk = {"bf16_flops": 1e12, "hbm_bw": 1e12}
-    rec = {"trace": trace.reduce(synthetic()), "model": m, "peaks": pk,
+    rec = {"trace": trace.reduce(synthetic()), "model": m, "arch": dense,
+           "peaks": pk,
            "engine": {"max_len": 4096},
            "counters": {"chunk_ticks": 2, "decode_forwards": 1,
                         "decode_row_forwards": 3, "tokens_processed": 35,
@@ -115,14 +117,14 @@ def test_readers_on_a_synthetic_record():
     assert read("device_idle_share") == pytest.approx(50.0)
     assert read("prefix_hit_share") == 0.0
     # 32 prompt tokens at mean 4.5 keys (positions 0..7) + one head
-    flops = costs.prefill_flops(m, 32, 1, 4.5)
+    flops = dense.prefill_flops(m, 32, 1, 4.5)
     assert read("prefill_mfu") == pytest.approx(100 * flops / (0.005 * 1e12))
     # two decode rows of one request at positions 8, 9: mean 9.5 keys
-    byts = costs.decode_bytes(m, 1, 3, 9.5)
+    byts = dense.decode_bytes(m, 1, 3, 9.5)
     assert read("decode_hbm_share") == pytest.approx(
         100 * byts / (0.001 * 1e12))
     # the whole window: the 32 prompt tokens and 3 decode rows at 9.5 keys
-    step = flops + 3 * costs.token_flops(m, 9.5, head=True)
+    step = flops + 3 * dense.token_flops(m, 9.5, head=True)
     assert read("step_mfu") == pytest.approx(
         100 * step / (rec["trace"]["window_s"] * 1e12))
     # the synthetic kernel call: q (384,16,128), kv (32,4128,128)
