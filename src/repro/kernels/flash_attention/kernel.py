@@ -160,15 +160,18 @@ def _ring_block_visible(iq, jk, bq, bkv, ring):
     concatenated KV axis (indices >= ring) is statically causal — a block
     whose lowest chunk offset exceeds the q block's highest offset can never
     contain a visible entry.  Ring-slot blocks are data-dependent (per-stream
-    positions) and are always entered; their masking is per-element."""
+    positions) and are always entered; their masking is per-element.  The
+    skip saves MXU work only: the block's keys and values are still
+    fetched."""
     q_hi = iq * bq + bq - 1
     kv_lo = jk * bkv
     return (kv_lo < ring) | (kv_lo - ring <= q_hi)
 
 
-def _ring_kv_positions(pos, n_tokens, jk, *, ring, bq, bkv):
-    """Absolute position held by each KV column of block ``jk`` for one
-    stream whose chunk starts at ``pos`` with ``n_tokens`` live tokens.
+def _ring_kv_positions(pos, n_tokens, jk, *, ring, bkv):
+    """(1, bkv) absolute position held by each KV column of block ``jk``
+    for one stream whose chunk starts at ``pos`` with ``n_tokens`` live
+    tokens.
 
     Columns [0, ring) are ring slots BEFORE the chunk is written: slot j
     holds the latest position p <= pos-1 with p = j (mod ring), negative
@@ -178,7 +181,7 @@ def _ring_kv_positions(pos, n_tokens, jk, *, ring, bq, bkv):
     last = pos - 1
     r = jax.lax.rem(last, ring)                        # scalar floor mod
     r = jnp.where(r < 0, r + ring, r)
-    j = jk * bkv + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
+    j = jk * bkv + jax.lax.broadcasted_iota(jnp.int32, (1, bkv), 1)
     ring_pos = last - r + j - jnp.where(j <= r, 0, ring)
     t = j - ring
     chunk_pos = jnp.where(t < n_tokens, pos + t, NEVER)
@@ -187,11 +190,12 @@ def _ring_kv_positions(pos, n_tokens, jk, *, ring, bq, bkv):
 
 def _ring_fwd_kernel(pos_ref, nt_ref, q_ref, k_ref, v_ref, o_ref,
                      m_scr, l_scr, acc_scr, *, scale, ring, window, softcap,
-                     bq, bkv, n_kv, heads):
-    b = pl.program_id(0) // heads
+                     bq, bkv, n_kv, kv_heads):
+    b = pl.program_id(0) // kv_heads
     iq = pl.program_id(1)
     jk = pl.program_id(2)
     pos = pos_ref[b]
+    G, _, dh = q_ref.shape
 
     @pl.when(jk == 0)
     def _init():
@@ -201,50 +205,53 @@ def _ring_fwd_kernel(pos_ref, nt_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(_ring_block_visible(iq, jk, bq, bkv, ring))
     def _compute():
-        q = q_ref[0]                                   # (bq, dh)
+        # the G query heads that share this KV head score the one KV tile
+        # as G*bq rows of a single matmul
+        q = q_ref[...].reshape(G * bq, dh)
         k = k_ref[0]                                   # (bkv, dh)
         v = v_ref[0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (bq, bkv)
+            preferred_element_type=jnp.float32) * scale  # (G*bq, bkv)
         if softcap:
             s = softcap * jnp.tanh(s / softcap)
+        s = s.reshape(G, bq, bkv)
         # absolute positions, rebuilt here from the stream's two prefetched
         # scalars: q rows are pos+t, KV columns as in _ring_kv_positions.
         # One band test then expresses all three dense masks: causality
         # (kp <= qp), ring eviction incl. intra-chunk self-eviction for
-        # C > W (kp > qp - ring), and never-written slots (kp >= 0).
-        qp = pos + iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
-        kp = _ring_kv_positions(pos, nt_ref[b], jk, ring=ring, bq=bq,
-                                bkv=bkv)
+        # C > W (kp > qp - ring), and never-written slots (kp >= 0).  The
+        # (bq, bkv) mask is the same for every head of the group.
+        qp = pos + iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+        kp = _ring_kv_positions(pos, nt_ref[b], jk, ring=ring, bkv=bkv)
         mask = (kp >= 0) & (kp <= qp) & (kp > qp - ring)
         if window:
             mask &= kp > qp - window
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_scr[:, 0]                           # (bq,)
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[:, :, :1]                       # (G, bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_scr[:, 0] * alpha + p.sum(axis=-1)
-        acc_scr[...] = (acc_scr[...] * alpha[:, None] +
-                        jax.lax.dot_general(
-                            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32))
-        m_scr[...] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new[:, None], l_scr.shape)
+        l_new = l_scr[:, :, :1] * alpha + p.sum(axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.reshape(G * bq, bkv).astype(v.dtype), v,
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        acc_scr[...] = acc_scr[...] * alpha + pv.reshape(G, bq, dh)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
     @pl.when(jk == n_kv - 1)
     def _finalize():
-        l = l_scr[:, 0]
+        l = l_scr[:, :, :1]
         denom = jnp.where(l > 0, l, 1.0)
-        o_ref[0] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
-def ring_chunk_attention_fwd(q, k, v, pos, n_tokens, *, ring, window=0,
-                             softcap=0.0, block_q=32, block_kv=32,
-                             hq_per_kv=1, interpret=None):
+def ring_chunk_attention_fwd(q, k, v, pos, n_tokens, *, ring, block_q,
+                             block_kv, window=0, softcap=0.0, hq_per_kv=1,
+                             interpret=None):
     """Forward-only blocked attention over [prior ring, chunk keys].
 
     q: (BHq, Cp, dh) chunk queries, head-major, padded to a block_q
@@ -253,46 +260,47 @@ def ring_chunk_attention_fwd(q, k, v, pos, n_tokens, *, ring, window=0,
     token 0; n_tokens: (B,) int32 live chunk tokens.  ``ring`` is the ring
     width W (the implicit eviction window).  Returns (BHq, Cp, dh).
 
-    ``pos``/``n_tokens`` ride in SMEM as scalar-prefetch operands and every
-    query/KV position is derived in-kernel, so no (B, L) position array
-    needs a lane-aligned VMEM block.  The live transient per grid step is
-    one (block_q, block_kv) f32 score block plus the online-softmax state
-    — never the dense (C, W+C) block.
+    Grid (BHkv, Cp / block_q, Lp / block_kv): a step scores the
+    ``hq_per_kv`` query heads that share a KV head against one KV tile,
+    fetched once for all of them.  ``pos``/``n_tokens`` ride in SMEM as
+    scalar-prefetch operands and every query/KV position is derived
+    in-kernel, so no (B, L) position array needs a lane-aligned VMEM
+    block.  The live transient per grid step is one
+    (hq_per_kv * block_q, block_kv) f32 score block plus the
+    online-softmax state — never the dense (C, W+C) block.
     """
     BH, Cp, dh = q.shape
-    Lp = k.shape[1]
+    BHkv, Lp, _ = k.shape
     B = pos.shape[0]
-    heads = BH // B
-    bq = min(block_q, Cp)
-    bkv = min(block_kv, Lp)
-    assert Cp % bq == 0 and Lp % bkv == 0
-    n_q, n_kv = Cp // bq, Lp // bkv
-    scale = dh ** -0.5
     G = hq_per_kv
+    assert BH == BHkv * G and Cp % block_q == 0 and Lp % block_kv == 0
+    n_q, n_kv = Cp // block_q, Lp // block_kv
 
     kernel = functools.partial(
-        _ring_fwd_kernel, scale=scale, ring=ring, window=window,
-        softcap=softcap, bq=bq, bkv=bkv, n_kv=n_kv, heads=heads)
+        _ring_fwd_kernel, scale=dh ** -0.5, ring=ring, window=window,
+        softcap=softcap, bq=block_q, bkv=block_kv, n_kv=n_kv,
+        kv_heads=BHkv // B)
 
     out = pl.pallas_call(
         kernel,
         name="ring_chunk_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(BH, n_q, n_kv),
+            grid=(BHkv, n_q, n_kv),
             in_specs=[
-                pl.BlockSpec((1, bq, dh), lambda b, iq, jk, *_: (b, iq, 0)),
-                pl.BlockSpec((1, bkv, dh),
-                             lambda b, iq, jk, *_: (b // G, jk, 0)),
-                pl.BlockSpec((1, bkv, dh),
-                             lambda b, iq, jk, *_: (b // G, jk, 0)),
+                pl.BlockSpec((G, block_q, dh),
+                             lambda b, iq, jk, *_: (b, iq, 0)),
+                pl.BlockSpec((1, block_kv, dh),
+                             lambda b, iq, jk, *_: (b, jk, 0)),
+                pl.BlockSpec((1, block_kv, dh),
+                             lambda b, iq, jk, *_: (b, jk, 0)),
             ],
-            out_specs=pl.BlockSpec((1, bq, dh),
+            out_specs=pl.BlockSpec((G, block_q, dh),
                                    lambda b, iq, jk, *_: (b, iq, 0)),
             scratch_shapes=[
-                pltpu.VMEM((bq, LANES), jnp.float32),
-                pltpu.VMEM((bq, LANES), jnp.float32),
-                pltpu.VMEM((bq, dh), jnp.float32),
+                pltpu.VMEM((G, block_q, LANES), jnp.float32),
+                pltpu.VMEM((G, block_q, LANES), jnp.float32),
+                pltpu.VMEM((G, block_q, dh), jnp.float32),
             ]),
         out_shape=jax.ShapeDtypeStruct((BH, Cp, dh), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -358,8 +358,8 @@ def decode_attention(q, k_cache, v_cache, kv_positions, q_pos, *,
 
 
 def chunk_attention(q, k_new, v_new, k_cache, v_cache, pos, n_tokens, *,
-                    window=0, softcap=0.0, kernel="dense", block_q=32,
-                    block_kv=32, interpret=None):
+                    window=0, softcap=0.0, kernel="dense", block_q=None,
+                    block_kv=None, interpret=None):
     """Multi-token chunk attention over a ring cache: ONE fused score
     computation instead of C sequential decode steps.
 
@@ -379,7 +379,8 @@ def chunk_attention(q, k_new, v_new, k_cache, v_cache, pos, n_tokens, *,
     ``kernel`` selects the score computation: "dense" materializes the
     (B, H, C, W+C) block below (the reference, priced by
     ``costmodel.prefill_chunk_score_bytes``); "blocked" streams KV in
-    (block_q, block_kv) tiles through the Pallas online-softmax kernel
+    (block_q, block_kv) tiles, chosen from the shapes unless given, through
+    the Pallas online-softmax kernel
     (``kernels.flash_attention.ops.ring_chunk_attention``) so the live
     transient never exceeds one tile.  Both are exact for chunks wider
     than the ring (C > W): intra-chunk self-eviction is the same band

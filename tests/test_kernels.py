@@ -9,7 +9,9 @@ from conftest import hypothesis_tools
 
 given, settings, st = hypothesis_tools()
 
-from repro.kernels.flash_attention.ops import (flash_attention,
+from repro.kernels.flash_attention.ops import (RING_MAX_ROWS,
+                                               flash_attention,
+                                               ring_blocks,
                                                ring_chunk_attention)
 from repro.kernels.flash_attention.ref import gqa_attention_ref
 from repro.kernels.rglru_scan.ops import lru
@@ -191,6 +193,53 @@ def test_ring_chunk_attention_property(seed):
     bkv = int(rng.choice([5, 8, 16, 32]))
     _ring_case(B, C, W, G * Hkv, Hkv, dh, pos, nt, window, softcap, bq,
                bkv, jnp.float32, seed=seed % 1009)
+
+
+# blocks chosen from the shapes (``ring_blocks``), as the engine runs them
+RING_SHAPE_SWEEP = [
+    # B, C, W, Hq, Hkv, dh, pos, nt, window, softcap, dtype
+    (2, 16, 4096, 12, 2, 128, (4100, 37), (16, 9), 0, 0.0, jnp.bfloat16),
+    (1, 16, 512, 12, 1, 64, (700,), (16,), 0, 0.0, jnp.float32),
+    (2, 16, 2048, 16, 1, 256, (3000, 1500), (16, 11), 1000, 0.0,
+     jnp.float32),
+    (3, 5, 64, 8, 2, 32, (0, 70, 200), (5, 3, 5), 0, 0.0, jnp.float32),
+    (2, 24, 16, 4, 2, 32, (5, 40), (24, 17), 0, 0.0, jnp.float32),
+    (3, 16, 32, 6, 1, 32, (0, 0, 50), (0, 16, 16), 0, 0.0, jnp.float32),
+    (2, 16, 256, 8, 1, 64, (300, 10), (16, 12), 100, 30.0, jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize(
+    "B,C,W,Hq,Hkv,dh,pos,nt,window,softcap,dtype", RING_SHAPE_SWEEP,
+    ids=["group6_c16_bf16", "group12", "group16_window", "spec_verify_c5",
+         "chunk_wider_than_ring", "idle_rows_at_pos0",
+         "group8_window_softcap_bf16"])
+def test_ring_chunk_attention_shape_blocks_vs_dense(B, C, W, Hq, Hkv, dh,
+                                                    pos, nt, window,
+                                                    softcap, dtype):
+    _ring_case(B, C, W, Hq, Hkv, dh, pos, nt, window, softcap, None, None,
+               dtype, seed=B * 37 + C)
+
+
+@pytest.mark.parametrize("C,W,G,dh,dtype", [
+    (16, 4096, 6, 128, jnp.bfloat16), (16, 4096, 12, 128, jnp.bfloat16),
+    (16, 4096, 3, 128, jnp.bfloat16), (16, 2048, 16, 256, jnp.bfloat16),
+    (5, 4096, 6, 128, jnp.bfloat16), (512, 4096, 1, 128, jnp.bfloat16),
+    (7, 20, 2, 16, jnp.float32)])
+def test_ring_blocks_tile_the_chip(C, W, G, dh, dtype):
+    """Blocks chosen from the shapes: query rows on whole sublane tiles
+    covering the chunk (or capped), keys on whole 128-lane tiles, and the
+    padding of the keys past W + C under 3% wherever the ring is long
+    enough for 128-lane tiles to allow it."""
+    bq, bkv = ring_blocks(C, W, G, dh, dtype)
+    sub = 32 // jnp.dtype(dtype).itemsize
+    assert bq % sub == 0 and G * bq <= max(RING_MAX_ROWS, G * sub)
+    assert bq >= C or G * (bq + sub) > RING_MAX_ROWS
+    assert bkv % 128 == 0
+    L = W + C
+    Lp = -(-L // bkv) * bkv
+    if L >= 4096:
+        assert Lp <= 1.03 * L and Lp // bkv <= 4
 
 
 def test_ring_chunk_attention_idle_stream_at_pos0_returns_zeros():

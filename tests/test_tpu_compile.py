@@ -12,6 +12,8 @@ test worker imports this file.
 import dataclasses
 import os
 import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -22,11 +24,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec, \
 
 from repro.configs import REGISTRY, reduced_config
 from repro.kernels.flash_attention.ops import (flash_attention,
+                                               ring_blocks,
                                                ring_chunk_attention)
 from repro.kernels.rglru_scan.kernel import lru_scan
 from repro.models import decode as dec
 from repro.models.decode import replicate_over
 from repro.models.params import abstract_params
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+from bench.metrics import ring_kernel_roofline  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -79,6 +85,45 @@ def test_ring_chunk_kernel_compiles_for_v5e(one_chip, W):
     calls = [ln for ln in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert calls and all("%ring_chunk_attention" in ln for ln in calls)
+
+
+@pytest.mark.parametrize("B,Hq,Hkv,dh,W,window", [
+    (4, 48, 8, 128, 4096, 0), (1, 48, 8, 128, 4096, 0),
+    (2, 16, 1, 256, 2048, 2048)],
+    ids=["nemotron_4_rows", "nemotron_1_row", "group16_dh256_window"])
+def test_ring_chunk_kernel_at_engine_shapes(one_chip, monkeypatch, B, Hq,
+                                            Hkv, dh, W, window):
+    """Nemotron-4-15B's chunk step (48 query heads over 8 KV heads of 128,
+    a 16-token chunk over a 4096-token ring) and a 16-head group of width
+    256 over a 2048-token window compile with the blocks chosen from the
+    shapes: one KV tile serves a whole query-head group, the keys take a
+    few large tiles, and the custom call keeps the operands the roofline
+    metric reads (result (B*Hq, Cp, dh), keys and values (B*Hkv, Lp, dh))."""
+    C, bf = 16, jnp.bfloat16
+    sd = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    compiled = _compile(
+        lambda q, kn, vn, kc, vc, p, n: ring_chunk_attention(
+            q, kn, vn, kc, vc, p, n, window=window, interpret=False),
+        sd((B, C, Hq, dh), bf), sd((B, C, Hkv, dh), bf),
+        sd((B, C, Hkv, dh), bf), sd((B, W, Hkv, dh), bf),
+        sd((B, W, Hkv, dh), bf), sd((B,), jnp.int32), sd((B,), jnp.int32))
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1 and "%ring_chunk_attention" in calls[0]
+
+    bq, bkv = ring_blocks(C, W, Hq // Hkv, dh, bf)
+    Cp, Lp = -(-C // bq) * bq, -(-(W + C) // bkv) * bkv
+    assert Lp // bkv <= 4
+    if W == 4096:
+        assert Lp <= 1.03 * (W + C)
+    seen = {}
+    monkeypatch.setattr(
+        ring_kernel_roofline.costs, "ring_kernel_cost",
+        lambda bhq, cp, bhkv, lp, dh, **_: seen.update(
+            bhq=bhq, cp=cp, bhkv=bhkv, lp=lp, dh=dh) or {})
+    ring_kernel_roofline._cost(calls[0], {"n_heads": Hq, "n_kv_heads": Hkv},
+                               W)
+    assert seen == dict(bhq=B * Hq, cp=Cp, bhkv=B * Hkv, lp=Lp, dh=dh)
 
 
 def test_ring_chunk_kernel_compiles_over_four_chips(topo):
